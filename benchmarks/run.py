@@ -414,6 +414,9 @@ def _ensure_dryrun(path: str, arch: str = "qwen1.5-0.5b") -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in ("src", env.get("PYTHONPATH", "")) if p)
+    # the dry-run studies placeholder CPU devices; this process may hold
+    # the accelerator, which a child cannot share
+    env["JAX_PLATFORMS"] = "cpu"
     subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", "train_4k", "--mesh", "single", "--out", path],
@@ -470,6 +473,9 @@ def main() -> None:
     ap.add_argument("--bus-steps", type=int, default=96,
                     help="training steps per bus_scaling cell")
     args = ap.parse_args()
+    from repro.utils import compile_cache
+
+    compile_cache.enable()
     if args.smoke:
         failures = smoke(bench_dir=args.bench_dir if args.bench else None)
         if failures or not args.bench:

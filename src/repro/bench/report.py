@@ -7,7 +7,7 @@ the files and later PRs can be judged against the recorded numbers::
       "schema":  "repro.bench/v1",
       "name":    "train_throughput",          # -> BENCH_train_throughput.json
       "created_unix": 1722470400.0,
-      "env":     {"jax": "0.4.37", "backend": "cpu", "device_count": 8,
+      "env":     {"jax": "0.9.0", "backend": "cpu", "device_count": 8,
                   "python": "3.10.14"},
       "metrics": {"steps_per_s": 12.5, ...},  # numbers only, all finite
       "meta":    {...}                        # free-form provenance

@@ -39,8 +39,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.dist.sharding import annotate
+from repro.dist.sharding import annotate, batch_axes, current_mesh
 from repro.hardware.mrr import MRRConfig
 from repro.lint.runtime import check_finite
 from repro.utils import prng
@@ -255,6 +256,9 @@ class PhotonicBackend:
     # calibration residuals): the Trainer then creates, advances, and
     # threads a per-ring state pytree through fit (see repro.hardware).
     stateful_hardware = False
+    # False when the product runs in a Pallas kernel: XLA cannot partition
+    # one, so under a mesh ``photonic_project`` runs it per shard
+    partitionable = True
 
     def matmul(self, a, b, cfg: PhotonicConfig, key=None, *, mask=None):
         raise NotImplementedError
@@ -278,6 +282,7 @@ class PallasBackend(PhotonicBackend):
 
     name: str = "pallas"
     interpret: bool = False
+    partitionable = False
 
     def matmul(self, a, b, cfg, key=None, *, mask=None):
         from repro.kernels import ops as kops  # lazy: kernels import us
@@ -302,6 +307,7 @@ class EmulatedMRRBackend(PhotonicBackend):
 
     name: str = "emu"
     stateful_hardware = True
+    partitionable = False
     emu_kernel: str = "auto"
 
     def matmul(self, a, b, cfg, key=None, *, mask=None):
@@ -340,12 +346,60 @@ def get_backend(spec: str | PhotonicBackend = "auto") -> PhotonicBackend:
 def photonic_project(e, b, cfg: PhotonicConfig, key=None, *, mask=None,
                      backend: str | PhotonicBackend = "auto"):
     """DFA projection  δ = e·Bᵀ (⊙ mask)  through a registered backend.
-    e: (..., d_tap), b: (d_out, d_tap)."""
+    e: (..., d_tap), b: (d_out, d_tap).
+
+    Under an active mesh (``sharding.use_mesh``) a backend whose product
+    is a Pallas kernel runs per device on its rows of ``e``: the SPMD
+    partitioner cannot split a kernel call (see ``_per_shard_matmul``)."""
     lead = e.shape[:-1]
     e2 = e.reshape(-1, e.shape[-1])
     m2 = mask.reshape(-1, mask.shape[-1]) if mask is not None else None
-    out = get_backend(backend).matmul(e2, b, cfg, key=key, mask=m2)
+    be = get_backend(backend)
+    mesh = current_mesh()
+    if mesh is None or be.partitionable:
+        out = be.matmul(e2, b, cfg, key=key, mask=m2)
+    else:
+        out = _per_shard_matmul(be, mesh, e2, b, cfg, key, m2)
     return out.reshape(*lead, b.shape[0])
+
+
+def _per_shard_matmul(backend: PhotonicBackend, mesh, a, b, cfg, key, mask):
+    """``backend.matmul`` inside ``shard_map`` over the mesh's batch axes.
+
+    Each device projects its own rows of ``a`` (and ``mask``) through a
+    replicated ``b``, as a data-parallel replica with a bank of its own
+    would: the amplitude encoding is per shard, and the noise key is folded
+    with the shard's index so that shards draw independent noise.  Carried
+    hardware state (``drift.use_state``) enters replicated.  Rows that do
+    not divide over the devices stay replicated and every device computes
+    the whole product."""
+    from repro.hardware import drift  # lazy: hardware imports us
+
+    axes = batch_axes(mesh)
+    n_shards = math.prod(mesh.shape[ax] for ax in axes)
+    split = a.shape[0] % n_shards == 0
+    rows = P(axes) if split else P()
+    ops, specs = {"a": a, "b": b}, {"a": rows, "b": P()}
+    if mask is not None:
+        ops["mask"], specs["mask"] = mask, rows
+    if key is not None:
+        ops["key"], specs["key"] = key, P()
+    state = drift.active_state() if backend.stateful_hardware else None
+    if state is not None:
+        ops["state"], specs["state"] = state, P()
+
+    def shard(ops):
+        k = ops.get("key")
+        if k is not None and split:
+            k = jax.random.fold_in(k, jax.lax.axis_index(axes))
+        ctx = (drift.use_state(ops["state"]) if "state" in ops
+               else contextlib.nullcontext())
+        with ctx:
+            return backend.matmul(ops["a"], ops["b"], cfg, key=k,
+                                  mask=ops.get("mask"))
+
+    return jax.shard_map(shard, mesh=mesh, in_specs=(specs,), out_specs=rows,
+                         check_vma=False)(ops)
 
 
 # ---------------------------------------------------------------------------

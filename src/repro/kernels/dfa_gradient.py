@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.photonic_matmul import _CompilerParams, _gaussian_tile
+from repro.kernels.photonic_matmul import _gaussian_tile
 
 
 def _kernel(a_ref, b_ref, mask_ref, *rest, nk: int, noise_mode: str,
@@ -123,7 +123,7 @@ def dfa_gradient_pallas(
         out_specs=pl.BlockSpec((block_t, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, m), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_t, block_m), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -51,9 +51,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.lint.runtime import check_finite
 from repro.utils import prng
 
-# jax < 0.5 names it TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 # ---------------------------------------------------------------------------
 # threefry2x32 — inlined so the same counter→bits map runs inside the Pallas
 # kernel and in the XLA twin (plain uint32 vector ops, no pltpu PRNG needed,
@@ -107,7 +104,8 @@ def counter_gaussian(k0, k1, c0, c1):
     m = jnp.uint32(0xFFFF)
     s = ((b0 & m) + (b0 >> jnp.uint32(16))
          + (b1 & m) + (b1 >> jnp.uint32(16)))
-    return (s.astype(jnp.float32) - 131070.0) * _IH4_SCALE
+    # s < 2**19, so the int32 hop is exact; Mosaic has no uint32->f32 cast
+    return (s.astype(jnp.int32).astype(jnp.float32) - 131070.0) * _IH4_SCALE
 
 
 def _adc(part, adc_bits: int | None, amax: float):
@@ -242,19 +240,22 @@ def emu_bank_product_pallas(a_t, delta_eff, dead_mask, *, n_panels: int,
         sigma=sigma, shot=shot, adc_bits=adc_bits, amax=amax, rows=rows,
         block_t=bt, has_mask=dead_mask is not None, noisy=noisy)
 
+    # output row-panel major: a (bt, rows) block then spans the array's
+    # whole last dim, which Mosaic accepts for any bank height (a (bt, 50)
+    # block over a (T, nm*50) array is refused: 50 is not a lane multiple)
     out = pl.pallas_call(
         kern,
         grid=(t_pad // bt, nm, nj),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt, rows), lambda tb, i, j: (tb, i)),
-        out_shape=jax.ShapeDtypeStruct((t_pad, nm * rows), jnp.float32),
+        out_specs=pl.BlockSpec((None, bt, rows), lambda tb, i, j: (i, tb, 0)),
+        out_shape=jax.ShapeDtypeStruct((nm, t_pad, rows), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, rows), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*operands)
-    return out[:t]
+    return jnp.moveaxis(out[:, :t], 0, 1).reshape(t, nm * rows)
 
 
 # ---------------------------------------------------------------------------

@@ -84,15 +84,7 @@ def photonic_matmul(a, b, cfg, key=None, *, mask=None, noise_mode="auto",
         if interpret:
             # pltpu PRNG primitives need the TPU-semantics interpreter
             # (bits come back zero there — structure-only validation).
-            _InterpretParams = getattr(pltpu, "InterpretParams", None)
-            if _InterpretParams is not None:
-                interpret = _InterpretParams()
-            else:
-                # jax < 0.5: the plain interpreter has no prng_seed rule.
-                # sigma_step=0 skips the PRNG ops inside the kernel while
-                # keeping the full prng-mode operand/grid structure — same
-                # zero-noise contract the TPU-semantics interpreter gives.
-                sigma_step = 0.0
+            interpret = pltpu.InterpretParams()
 
     if mask is not None:
         m_p = _pad_to(_pad_to(mask, bt, 0), bm, 1)

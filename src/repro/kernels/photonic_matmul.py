@@ -32,9 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams; newer releases renamed it.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _uniform_from_bits(bits):
     """uint32 -> uniform [0, 1) float32 using 24 high bits."""
@@ -155,7 +152,7 @@ def photonic_matmul_pallas(
         out_specs=pl.BlockSpec((block_t, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, m), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_t, block_m), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

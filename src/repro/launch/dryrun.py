@@ -5,10 +5,11 @@ import os
 # ALL stacked layer params/tape (+100s of GB at kimi-k2 scale).  On TPU bf16
 # is MXU-native and no such converts exist, so disabling the hoist gives the
 # memory profile the real machine would see.
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    "--xla_disable_hlo_passes=while-loop-invariant-code-motion"
-)
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512",
+    "--xla_disable_hlo_passes=while-loop-invariant-code-motion",
+)))
 
 """Multi-pod dry-run: lower + compile every (architecture × input-shape ×
 mesh) cell and record memory/cost/collective analysis.

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro import api, configs
 from repro.serve import Request
+from repro.utils import compile_cache
 
 
 def _pct(xs, q):
@@ -60,6 +61,7 @@ def main():
                     help="append obs metrics rows (JSONL) to PATH")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     photonic = args.backend not in ("auto",)
     hardware = args.hardware or ("emu_ideal" if photonic else "digital")

@@ -33,13 +33,14 @@ from repro import algos, api, configs
 from repro.core import photonics
 from repro.data import mnist, pipeline, tokens
 from repro.train import SGDM
+from repro.utils import compile_cache
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=configs.list_archs())
     ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced config (full configs are dry-run-only on CPU)")
+                    help="use the reduced config (default: the published widths)")
     ap.add_argument("--algo", choices=algos.list_algos(), default="dfa")
     ap.add_argument("--preset", choices=list(photonics.PRESETS), default="ideal")
     ap.add_argument("--backend", choices=["auto", *photonics.BACKENDS], default="auto")
@@ -88,10 +89,11 @@ def main():
     args = ap.parse_args()
     if args.power_budget_w is not None and not args.autotune:
         ap.error("--power-budget-w only steers --autotune")
+    compile_cache.enable()
 
     session = api.build_session(
         arch=args.arch,
-        smoke=(args.smoke or args.arch != "mnist_mlp"),
+        smoke=args.smoke,
         algo=args.algo,
         hardware=args.preset,
         backend=args.backend,

@@ -1,24 +1,2 @@
-import importlib.util
-import os
-import sys
-
-
-def _install_hypothesis_stub():
-    """The container image lacks hypothesis; substitute the minimal stub
-    (tests/_hypothesis_stub.py) so property tests still run as seeded
-    random-example batches.  No-op when real hypothesis is installed."""
-    if importlib.util.find_spec("hypothesis") is not None:
-        return
-    path = os.path.join(os.path.dirname(__file__), "_hypothesis_stub.py")
-    spec = importlib.util.spec_from_file_location("hypothesis", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = mod.strategies
-
-
-_install_hypothesis_stub()
-
-
 def pytest_configure(config):
     config.addinivalue_line("markers", "slow: long-running (subprocess compile) tests")

@@ -215,6 +215,62 @@ def test_sharded_equivalence_on_8_forced_devices():
         assert out[algo]["batch_split"] == "data"
 
 
+_KERNEL_SUBPROC = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax, jax.numpy as jnp
+    from repro import api
+    from repro.core import photonics
+
+    backends = {
+        "pallas": (photonics.PallasBackend(interpret=True), "ideal"),
+        "emu": (photonics.EmulatedMRRBackend(emu_kernel="pallas"), "emu_ideal"),
+    }
+    key = jax.random.PRNGKey(5)
+    batch = None
+    out = {}
+    for name, (backend, hardware) in backends.items():
+        runs = {}
+        for dp in (True, False):
+            s = api.build_session(arch="mnist_mlp", smoke=True, algo="dfa",
+                                  hardware=hardware, backend=backend,
+                                  data_parallel=dp,
+                                  microbatches=1 if dp else 4,
+                                  log_every=10**9)
+            if batch is None:
+                batch = {"x": jax.random.normal(key, (32, s.model.in_dim)),
+                         "y": jax.random.randint(key, (32,), 0,
+                                                 s.model.n_classes)}
+            state, metrics = s.fit(lambda step: batch, total_steps=3,
+                                   verbose=False)
+            runs[dp] = (float(metrics["loss"]), state["params"])
+        diffs = jax.tree_util.tree_map(
+            lambda a, b: float(jnp.max(jnp.abs(a - b))),
+            runs[True][1], runs[False][1])
+        out[name] = {"loss_diff": abs(runs[True][0] - runs[False][0]),
+                     "param_diff": max(jax.tree_util.tree_leaves(diffs))}
+    print(json.dumps(out))
+""")
+
+
+@pytest.mark.slow
+def test_kernel_backends_train_per_shard_on_4_forced_devices():
+    """The Pallas and emu kernels cannot be split by the SPMD partitioner,
+    so under a data mesh each device projects its own rows; four-way data
+    parallel fit matches one device running the batch as four
+    microbatches (each encoded by its own bank in both cases)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    proc = subprocess.run([sys.executable, "-c", _KERNEL_SUBPROC], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    for name in ("pallas", "emu"):
+        assert out[name]["loss_diff"] < 1e-5
+        assert out[name]["param_diff"] < 1e-5
+
+
 # ---------------------------------------------------------------------------
 # bench: schema round-trip + telemetry units
 # ---------------------------------------------------------------------------

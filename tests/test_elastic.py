@@ -48,7 +48,8 @@ _SUBPROC = textwrap.dedent("""
     path, mode = sys.argv[1], sys.argv[2]
     model = configs.get("qwen3-1.7b").make_smoke()
     params = model.init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((%d, %d), ("data", "model"))
+    mesh = jax.make_mesh((%d, %d), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh = sharding.make_param_shardings(mesh, params)
     if mode == "save":
         placed = jax.tree_util.tree_map(jax.device_put, params, sh)

@@ -51,7 +51,8 @@ _SUBPROC = textwrap.dedent("""
     from repro.dist import sharding
     from repro.train.optimizer import SGDM
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     arch = configs.get("qwen3-1.7b")
     model = arch.make_smoke()
     cfg = DFAConfig()
@@ -81,8 +82,6 @@ _SUBPROC = textwrap.dedent("""
         compiled = fn.lower(params_s, fb_s, opt_s, batch,
                             jax.ShapeDtypeStruct((), jnp.int32)).compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     print(json.dumps({"ok": True, "flops": float(ca.get("flops", 0))}))
 """)
 
