@@ -1,0 +1,8 @@
+"""Share of the traced serving window in which no op ran on the device
+(averaged over the chips), in percent."""
+
+import harness
+
+
+def read(r):
+    return harness.idle_share(r)

@@ -1,0 +1,9 @@
+"""Share of the traced training window in which no op ran on the device
+(averaged over the chips), in percent.
+(The MNIST cell's, which moves ``train_step_ms.mnist``.)"""
+
+import harness
+
+
+def read(r):
+    return harness.idle_share(r)
