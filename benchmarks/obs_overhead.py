@@ -5,7 +5,7 @@ the model shape BENCH_emu_kernel gates — on the device-level ``emu``
 backend with the fused ``xla`` kernel) twice:
 
 * observer **off** — ``fit(observer=None)``: the null-observer fast path
-  (shared no-op context manager, one batched ``jax.device_get`` per
+  (spans are bare profiler annotations, one batched ``jax.device_get`` per
   logging interval);
 * observer **on**  — a fully-wired ``obs.Observer``: per-step trace
   spans, recalibration instants, hardware monitor (drift vs the OU

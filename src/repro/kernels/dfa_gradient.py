@@ -127,4 +127,6 @@ def dfa_gradient_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="dfa_gradient",
+        metadata={"kernel": "dfa_gradient"},
     )(*operands)

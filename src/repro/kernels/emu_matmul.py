@@ -254,6 +254,8 @@ def emu_bank_product_pallas(a_t, delta_eff, dead_mask, *, n_panels: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="emu_bank",
+        metadata={"kernel": "emu_bank"},
     )(*operands)
     return jnp.moveaxis(out[:, :t], 0, 1).reshape(t, nm * rows)
 

@@ -156,6 +156,8 @@ def photonic_matmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="photonic_matmul",
+        metadata={"kernel": "photonic_matmul"},
     )(*operands)
 
 

@@ -42,15 +42,25 @@ tables (alignment and noise-budget tables included);
 observer's and the probe's cost (BENCH_obs.json / BENCH_alignment.json,
 CI-gated).
 
-``NULL`` is the disabled-observer fast path: every method is a no-op and
-``span`` returns one shared reusable context manager, so instrumented
-code pays a constant few attribute lookups — no allocation — when
-observability is off.
+Every span, on ``Observer`` and ``NULL`` alike, enters a
+``jax.profiler.TraceAnnotation`` (``step_span`` a ``StepTraceAnnotation``
+carrying the step number), so it lands in the profiler's ``.xplane.pb``
+on the clock of the device ops whenever a profile is recorded.
+``Observer`` writes the Chrome event as well; instants, counters,
+request tracks and ``repro.sim`` timelines stay on the Chrome exporter
+alone.
+
+``NULL`` is the disabled-observer fast path: every method but the spans
+is a no-op, and a span is one profiler annotation (well under a
+microsecond when no profile is recorded), so instrumented code calls the
+observer unconditionally.
 """
 
 from __future__ import annotations
 
 import contextlib
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.obs import export
 from repro.obs.anomaly import AnomalyAlert, AnomalyDetector
@@ -95,8 +105,19 @@ class Observer:
         self._alerts_emitted = 0
 
     # ---- tracing passthrough ----
+    @contextlib.contextmanager
     def span(self, name: str, **args):
-        return self.trace.span(name, **args)
+        """A profiler annotation and a Chrome "X" event; ``args`` go to
+        the Chrome event only."""
+        with TraceAnnotation(name), self.trace.span(name, **args):
+            yield
+
+    @contextlib.contextmanager
+    def step_span(self, name: str, step: int):
+        """``span`` for one step: the annotation carries ``step_num``."""
+        with StepTraceAnnotation(name, step_num=step), \
+                self.trace.span(name, step=step):
+            yield
 
     def event(self, name: str, **args) -> None:
         self.trace.instant(name, **args)
@@ -164,18 +185,21 @@ class Observer:
 
 
 class NullObserver:
-    """Disabled observability: constant-cost no-ops, zero allocation.
+    """Disabled observability: records nothing.
 
-    ``span`` hands back one shared reusable ``nullcontext`` and every
-    other method returns immediately, so hot loops can call the observer
-    unconditionally.
+    ``span`` and ``step_span`` hand back the bare profiler annotation
+    (``args`` dropped), so a profile still shows the program's spans;
+    every other method returns immediately, so hot loops can call the
+    observer unconditionally.
     """
 
     enabled = False
-    _NULL_CTX = contextlib.nullcontext()
 
     def span(self, name: str, **args):
-        return self._NULL_CTX
+        return TraceAnnotation(name)
+
+    def step_span(self, name: str, step: int):
+        return StepTraceAnnotation(name, step_num=step)
 
     def event(self, name: str, **args) -> None:
         pass

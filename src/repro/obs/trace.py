@@ -21,7 +21,10 @@ load directly):
 Timestamps are microseconds on a monotonic clock, zeroed at recorder
 creation, so traces are immune to wall-clock steps and line up with the
 engine/trainer ``time.monotonic`` measurements.  ``repro.obs.export``
-serializes the recorder to a Perfetto-loadable JSON file.
+serializes the recorder to a Perfetto-loadable JSON file.  The spans that
+instrumented code opens through ``repro.obs.Observer`` also enter a
+``jax.profiler.TraceAnnotation``, which puts them on the device
+profile's clock; this recorder's copy is for ``--trace-out``.
 """
 
 from __future__ import annotations

@@ -294,16 +294,25 @@ class Trainer:
                                   for k in self._log_keys) + "\n")
         self._log_file.flush()
 
-    def _make_feed(self, data_fn, total_steps: int):
+    def _make_feed(self, data_fn, total_steps: int, observer):
         """Wrap data_fn with the device-put (sharded under a mesh) and the
-        double-buffered prefetcher so fit's input feeding is off-path."""
+        double-buffered prefetcher so fit's input feeding is off-path;
+        each call of either is a span (``train.data_fn``, ``train.put``)."""
+        put_batch = jax.device_put
         if self.mesh is not None:
-            put = lambda batch: sharding.put_batch(self.mesh, batch)  # noqa: E731
-        else:
-            put = jax.device_put
+            put_batch = lambda batch: sharding.put_batch(self.mesh, batch)  # noqa: E731
+
+        def load(step):
+            with observer.span("train.data_fn"):
+                return data_fn(step)
+
+        def put(batch):
+            with observer.span("train.put"):
+                return put_batch(batch)
+
         if self.cfg.prefetch <= 0:
-            return lambda step: put(data_fn(step))
-        return DevicePrefetcher(data_fn, put_fn=put, depth=self.cfg.prefetch,
+            return lambda step: put(load(step))
+        return DevicePrefetcher(load, put_fn=put, depth=self.cfg.prefetch,
                                 limit=total_steps)
 
     def fit(self, data_fn, total_steps: int, eval_fn=None, verbose=True,
@@ -314,12 +323,19 @@ class Trainer:
         step is synced (block_until_ready) and its wall time recorded —
         bench-only, since the sync serializes dispatch.
 
-        ``observer`` is an optional ``repro.obs.Observer``: every step
-        gets a dispatch span, recalibration steps an instant event, and
-        each logging interval drains the device metrics through
-        ``observer.log_step`` (one batched ``jax.device_get``, hwmon
-        gauges + drift-budget alerts included).  ``None`` resolves to the
-        shared null observer — a constant-cost no-op path.
+        Spans, on the profiler's clock whatever the observer:
+        ``train.step`` (one loop iteration, with its step number) holds
+        ``train.dispatch`` (the jitted step's enqueue), ``train.probe``
+        and the logging interval's ``train.drain``; ``train.data_fn`` and
+        ``train.put`` wrap each batch made and enqueued, which the
+        prefetcher does up to ``cfg.prefetch`` steps ahead.
+
+        ``observer`` is an optional ``repro.obs.Observer``: it records
+        those spans in its Chrome trace too, recalibration steps as an
+        instant event, and drains each logging interval's device metrics
+        through ``observer.log_step`` (one batched ``jax.device_get``,
+        hwmon gauges + drift-budget alerts included).  ``None`` resolves
+        to the shared null observer, whose spans are bare annotations.
 
         With ``cfg.probe_every`` set, every probe_every-th step first
         runs the ``obs.introspect.AlignmentProbe`` on the step's own
@@ -344,61 +360,55 @@ class Trainer:
         state, start = self.restore_or_init()
         if self.mesh is not None:
             state = sharding.replicate(self.mesh, state)
-        feed = self._make_feed(data_fn, total_steps)
+        feed = self._make_feed(data_fn, total_steps, observer)
         metrics = {}
         recal = self.cfg.recalibrate_every if self._hw_stateful else 0
         if timer is not None:
             timer.start()
         try:
             for step in range(start, total_steps):
-                batch = feed(step)
-                if timer is not None and timer.examples_per_step is None:
-                    leaves = jax.tree_util.tree_leaves(batch)
-                    if leaves and getattr(leaves[0], "ndim", 0) >= 1:
-                        timer.examples_per_step = int(leaves[0].shape[0])
-                if probe is not None and step % self.cfg.probe_every == 0:
-                    # diagnostics BEFORE the update: alignment of the DFA
-                    # update this step is about to apply, on its own batch
-                    with observer.span("probe", step=step):
-                        with self._mesh_ctx():
-                            probed = probe(state, batch)
-                        probe_host = observer.log_step(step, probed)
-                    if verbose:
-                        print(f"[probe {step}] align_global="
-                              f"{probe_host.get('align_global', float('nan')):.4f}",
-                              flush=True)
-                if observer.enabled:
-                    # the span covers dispatch (async under jit — device time
-                    # shows up in the logging-interval drain span instead)
-                    with observer.span("step", step=step,
-                                       microbatches=self.cfg.microbatches):
-                        state, metrics = self._dispatch(state, batch,
-                                                        self._fit_step_fn)
+                with observer.step_span("train.step", step):
+                    batch = feed(step)
+                    if timer is not None and timer.examples_per_step is None:
+                        leaves = jax.tree_util.tree_leaves(batch)
+                        if leaves and getattr(leaves[0], "ndim", 0) >= 1:
+                            timer.examples_per_step = int(leaves[0].shape[0])
+                    if probe is not None and step % self.cfg.probe_every == 0:
+                        # diagnostics BEFORE the update: alignment of the DFA
+                        # update this step is about to apply, on its own batch
+                        with observer.span("train.probe", step=step):
+                            with self._mesh_ctx():
+                                probed = probe(state, batch)
+                            probe_host = observer.log_step(step, probed)
+                        if verbose:
+                            print(f"[probe {step}] align_global="
+                                  f"{probe_host.get('align_global', float('nan')):.4f}",
+                                  flush=True)
+                    # async under jit: device time shows in the drain, not here
+                    with observer.span("train.dispatch"):
+                        state, metrics = self._dispatch(state, batch, self._fit_step_fn)
                     if recal > 0 and step > 0 and step % recal == 0:
                         # mirrors hw_calibrate.advance's cadence inside the step
                         observer.event("recalibration", cat="hwmon", step=step)
-                else:
-                    state, metrics = self._dispatch(state, batch,
-                                                    self._fit_step_fn)
-                if timer is not None:
-                    timer.tick(state["step"])
-                if (step + 1) % self.cfg.log_every == 0 or step + 1 == total_steps:
-                    if observer.enabled:
-                        with observer.span("drain", step=step + 1):
-                            host = observer.log_step(step + 1, metrics)
-                    else:
-                        # one batched transfer for the whole dict — never one
-                        # blocking float() per metric; the floats below read
-                        # host memory, not the device
-                        host = {k: float(v) for k, v in  # lint: disable=RL002
-                                jax.device_get(dict(metrics)).items()}  # lint: disable=RL002
-                    self._log(step + 1, host)
-                    if verbose:
-                        txt = " ".join(f"{k}={v:.4f}"
-                                       for k, v in sorted(host.items()))
-                        print(f"[step {step + 1}/{total_steps}] {txt}", flush=True)
-                if self.ckpt is not None and (step + 1) % self.cfg.ckpt_every == 0:
-                    self.ckpt.save(step + 1, state)
+                    if timer is not None:
+                        timer.tick(state["step"])
+                    if (step + 1) % self.cfg.log_every == 0 or step + 1 == total_steps:
+                        with observer.span("train.drain", step=step + 1):
+                            if observer.enabled:
+                                host = observer.log_step(step + 1, metrics)
+                            else:
+                                # one batched transfer for the whole dict — never
+                                # one blocking float() per metric; the floats
+                                # below read host memory, not the device
+                                drained = jax.device_get(dict(metrics))  # lint: disable=RL002
+                                host = {k: float(v)  # lint: disable=RL002
+                                        for k, v in drained.items()}
+                            self._log(step + 1, host)
+                        if verbose:
+                            txt = " ".join(f"{k}={v:.4f}" for k, v in sorted(host.items()))
+                            print(f"[step {step + 1}/{total_steps}] {txt}", flush=True)
+                    if self.ckpt is not None and (step + 1) % self.cfg.ckpt_every == 0:
+                        self.ckpt.save(step + 1, state)
         finally:
             # interrupted or not, buffered JSONL rows reach disk — an
             # aborted run leaves a parseable metrics file

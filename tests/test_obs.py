@@ -182,11 +182,18 @@ def test_hwmon_default_budget_and_dead_ring_threshold():
 # ---------------------------------------------------------------------------
 
 def test_null_observer_allocates_nothing():
+    """The null observer keeps nothing: no recorder, no rows.  The one
+    object a span makes is its profiler annotation, which carries the
+    name alone (the Chrome-event args are dropped)."""
     null = obs.resolve(None)
     assert null is obs.NULL and not null.enabled
-    # every span call hands back the one shared context manager
-    assert null.span("a") is null.span("b", x=1) is obs.NullObserver._NULL_CTX
-    with null.span("a"):
+    assert not hasattr(null, "trace") and not hasattr(null, "metrics")
+    span = null.span("a", x=1)
+    assert type(span) is jax.profiler.TraceAnnotation
+    assert type(null.step_span("s", 3)) is jax.profiler.StepTraceAnnotation
+    with span:
+        pass
+    with null.step_span("s", 3):
         pass
     null.event("e")
     null.counter("c", {"v": 1})
@@ -243,15 +250,73 @@ def test_fit_with_observer_records_steps_and_hw_gauges(tmp_path):
     session.fit(lambda s: {"x": x, "y": y}, total_steps=8, verbose=False)
     path = observer.close()
     doc = json.load(open(path))
-    steps = [e for e in doc["traceEvents"]
-             if e["ph"] == "X" and e["name"] == "step"]
-    assert len(steps) == 8
+    spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    steps = [e for e in spans if e["name"] == "train.step"]
+    assert [e["args"]["step"] for e in steps] == list(range(8))
+    names = [e["name"] for e in spans]
+    assert names.count("train.dispatch") == names.count("train.data_fn") == 8
+    assert names.count("train.put") == 8
+    drains = [e for e in spans if e["name"] == "train.drain"]
+    assert [e["args"]["step"] for e in drains] == [2, 4, 6, 8]
     recals = [e for e in doc["traceEvents"] if e["name"] == "recalibration"]
     assert {e["args"]["step"] for e in recals} == {4}
     rows = [json.loads(ln) for ln in open(tmp_path / "m.jsonl")]
     assert [r["step"] for r in rows] == [2, 4, 6, 8]  # log_every=2
     assert all("hw_effective_bits" in r["metrics"] for r in rows)
     assert all("loss" in r["metrics"] for r in rows)
+
+
+def _profiled_spans(log_dir) -> list:
+    """[(start_ns, end_ns, name, step_num or None)] of the ``train.*``
+    host spans in the one profile recorded under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    [path] = list(log_dir.glob("plugins/profile/*/*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("train."):
+                    stats = dict(e.stats)
+                    out.append((e.start_ns, e.start_ns + e.duration_ns, e.name,
+                                stats.get("step_num")))
+    return sorted(out)
+
+
+@pytest.mark.parametrize("prefetch", [2, 0])
+def test_fit_spans_reach_the_profile_without_an_observer(tmp_path, prefetch):
+    """``Session.fit`` with no observer still puts its spans in a profile:
+    one ``train.step`` per step with its step number, one
+    ``train.dispatch`` inside each, and one ``train.data_fn`` and one
+    ``train.put`` per batch inside a step.  The prefetcher (depth 2)
+    makes all three batches in step 0; without it each step makes its
+    own."""
+    session = api.build_session(arch="mnist_mlp", smoke=True, algo="dfa",
+                                prefetch=prefetch)
+    x = np.random.default_rng(0).normal(
+        size=(8, session.model.in_dim)).astype(np.float32)
+    y = np.zeros((8,), np.int32)
+    fit = lambda: session.fit(lambda s: {"x": x, "y": y},  # noqa: E731
+                              total_steps=3, verbose=False)
+    jax.block_until_ready(fit()[0])  # compile outside the profile
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(fit()[0])
+    spans = _profiled_spans(tmp_path)
+
+    steps = [sp for sp in spans if sp[2] == "train.step"]
+    assert [sp[3] for sp in steps] == [0, 1, 2]
+
+    def step_of(sp):
+        [k] = [st[3] for st in steps if st[0] <= sp[0] and sp[1] <= st[1]]
+        return k
+
+    batches = [0, 0, 0] if prefetch else [0, 1, 2]
+    want = {"train.dispatch": [0, 1, 2], "train.data_fn": batches,
+            "train.put": batches, "train.drain": [2]}
+    for name, in_steps in want.items():
+        assert [step_of(sp) for sp in spans if sp[2] == name] == in_steps, name
 
 
 def test_fit_without_observer_unchanged():

@@ -8,6 +8,8 @@ topology is described inside a fixture, so collecting this file loads no
 TPU library and a host that cannot describe it skips these tests.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -120,3 +122,40 @@ def test_projection_runs_per_shard_under_a_mesh(topo):
              if "tpu_custom_call" in ln and "custom-call(" in ln]
     assert calls and all(f"[{T // 4},{K}]" in ln for ln in calls)
     assert "all-gather" not in text
+
+
+def _kernel_cases(one_chip):
+    """name -> (fn, argument shapes) of each Pallas kernel, at small widths."""
+    bpd, emu = photonics.preset("offchip_bpd"), photonics.preset("emu_onchip")
+    key = _sds((2,), one_chip, jnp.uint32)
+    a, b = _sds((256, 128), one_chip), _sds((256, 128), one_chip)
+    return {
+        "emu_bank": (lambda a, b, key: emu_matmul.fused_bank_product(
+            a, b, emu, jax.random.wrap_key_data(key), impl="pallas",
+            interpret=False), (a, b, key)),
+        "photonic_matmul": (lambda a, b, key: ops.photonic_matmul(
+            a, b, bpd, key, noise_mode="prng"), (a, b, key)),
+        "dfa_gradient": (lambda a, b, mask, key: ops.dfa_gradient(
+            a, b, mask, bpd, key, noise_mode="prng"),
+            (a, b, _sds((256, 256), one_chip), key)),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["emu_bank", "photonic_matmul", "dfa_gradient"])
+def test_kernel_name_in_op_text(one_chip, kernel):
+    """Each kernel's call names it in its op text: in ``kernel_metadata``,
+    after the operands, which a profile's op event carries (and which a
+    signature match on the operands does not reach), and in the op's
+    ``op_name`` (``pallas_call``'s ``name``); no other kernel's name is
+    there."""
+    cases = _kernel_cases(one_chip)
+    fn, args = cases[kernel]
+    text = _assert_kernel(fn, *args)
+    # one HLO instruction each; the metadata's JSON spans lines
+    ops = re.split(r"\n(?= *(?:ROOT )?%)", text)
+    calls = [op.strip() for op in ops if 'custom_call_target="tpu_custom_call"' in op]
+    assert calls
+    for op in calls:
+        assert f'/{kernel}/pallas_call"' in op
+        assert op.index(f'"kernel":"{kernel}"') > op.index("custom-call(")
+        assert not [k for k in cases if k != kernel and f'"kernel":"{k}"' in op]
