@@ -93,8 +93,8 @@ def effective_deltas(w_target, cfg, residual=None):
     """The control-plane half of the inscription path: targets ->
     commanded heaters -> physical detunings (crosstalk leak + drift
     residual).  ``realized_weights`` maps these through the Lorentzian;
-    the fused kernels (``kernels.emu_matmul``) take them as-is and apply
-    the transfer in-kernel.
+    the fused path (``kernels.emu_matmul``) takes them as-is and applies
+    the transfer itself.
 
     ``w_target``: the bus-tiled (nm, n_alive, rows, nj, cols) layout, a
     bus-free (..., rows, nk, cols) panel stack, or a bare (rows, cols)

@@ -12,10 +12,15 @@ the accumulated (T, M) digital output is ever written back.
 
 Two implementations share the schedule and the PRNG bit-stream:
 
-* ``impl="pallas"`` — a Pallas TPU kernel (grid = row-blocks × output
-  row-panels × bus-cycles, f32 VMEM accumulator).  On non-TPU backends it
-  runs in the Pallas interpreter (slow — testing only; see ``kernels/ops``
-  for the same convention).
+* ``impl="pallas"`` — a Pallas TPU kernel (``tile_plan``): a grid of
+  token blocks × lane blocks × bus-cycle blocks, each step a lane-dense
+  (token rows × every output row panel) tile, side by side along the
+  lanes, accumulated in place over its cycles.  The epilogue (noise, ADC,
+  accumulation) runs per slot in register-sized row strips.  At qwen's
+  8192 × 1024 × 1024 projection that is 64 × 1 × 2 steps a call, where a
+  (128-token, one 50-row panel, one cycle) grid took 69,888.  On non-TPU
+  backends it runs in the Pallas interpreter (slow — testing only; see
+  ``kernels/ops`` for the same convention).
 * ``impl="xla"``    — the same fused slot loop lowered through
   ``lax.scan``: compiled on every backend, and the fast path for CPU/GPU
   hosts where Mosaic is unavailable.  This is what "compiled fused path"
@@ -34,14 +39,16 @@ PRNG stream); with noise off the two paths agree to f32 tolerance.
 Physics boundary: weight *inscription* (heater-DAC quantisation and the
 controller's Jacobi crosstalk pre-compensation) is control-plane work
 shared verbatim with the unfused path (``channel.effective_deltas``); the
-kernel takes the effective drift-perturbed detunings and applies the
-photonic part — Lorentzian transfer, dead-ring masking, the MAC, BPD
-noise, per-pass ADC — plus the digital accumulation.
+fused path takes the effective drift-perturbed detunings and applies the
+photonic part — Lorentzian transfer and dead-ring masking (once a call,
+ahead of the kernel), then the MAC, BPD noise and per-pass ADC — plus the
+digital accumulation.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +125,17 @@ def _adc(part, adc_bits: int | None, amax: float):
     return jnp.round(scaled) / levels * amax
 
 
+def _ring_transfer(delta_eff, dead_mask, gamma: float):
+    """Lorentzian BPD transfer of the (nm, Q, rows, NJ, C) effective
+    detunings; fabrication-dead rings read 0."""
+    g2 = gamma * gamma
+    d2 = jnp.square(delta_eff)
+    w = (d2 - g2) / (d2 + g2)
+    if dead_mask is not None:
+        w = w * dead_mask[None, :, :, None, :]
+    return w
+
+
 def _slot_noise(part, k0, k1, c0, c1, valid, sigma: float, shot: float):
     """Per-(bus,pass) BPD noise for one slot's (..., rows) partials: the
     thermal/read floor + signal-dependent shot noise, masked on idle padded
@@ -137,62 +155,124 @@ def _slot_noise(part, k0, k1, c0, c1, valid, sigma: float, shot: float):
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
+# VMEM the kernel's blocks may take: the output block (double-buffered),
+# the partial scratch and the double-buffered per-cycle input and transfer
+# blocks, inside v5e's 16 MiB scoped default
+_VMEM_BYTES = 14 << 20
+# Epilogue strip: rows × lanes per quantity, so that threefry's words, the
+# partial and the noise stay in the 64-entry vector register file (about
+# eight f32 vregs each)
+_STRIP_ELEMS = 8 * 1024
 
-def _emu_kernel(a_ref, d_ref, *rest, q_buses: int, nj: int, n_panels: int,
-                gamma: float, sigma: float, shot: float,
-                adc_bits: int | None, amax: float, rows: int, block_t: int,
-                has_mask: bool, noisy: bool):
-    """rest = [mask_ref?], [seed_ref?], o_ref, acc_ref."""
-    idx = 0
-    mask_ref = None
-    seed_ref = None
-    if has_mask:
-        mask_ref = rest[idx]
-        idx += 1
+
+class TilePlan(NamedTuple):
+    bt: int  # token rows per grid step
+    bm: int  # output lanes per grid step: a multiple of 128
+    nj_blk: int  # bus cycles per grid step
+    m_pad: int  # output lanes in all: nm·rows rounded up to whole blocks
+    grid: tuple[int, int, int]  # (token blocks, lane blocks, cycle blocks)
+    strip: int  # epilogue rows per inner-loop iteration
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def tile_plan(t: int, q_buses: int, nj: int, cols: int, m: int,
+              block_t: int = 128) -> TilePlan:
+    """The fused kernel's tiling for a (T, Q, NJ, C) × (C, M) bank product.
+
+    A grid step owns a lane-dense (bt, bm) output tile and runs nj_blk bus
+    cycles of all Q buses on it.  bt follows T (a short T is not padded to
+    ``block_t``); bm spans every row panel of the output (nm·rows rounded up
+    to 128 lanes) unless half the VMEM budget cannot hold that tile's
+    buffers and one cycle's transfer block, when the lanes split into equal
+    blocks; nj_blk is the most cycles whose double-buffered blocks fit the
+    rest, spread evenly over the fewest cycle blocks."""
+    bt = min(block_t, _round_up(t, 8))
+    c_lanes, c_rows = _round_up(cols, 128), _round_up(cols, 8)
+    lanes = _round_up(m, 128)
+    lane_cap = (_VMEM_BYTES // 2) // (4 * (3 * bt + 2 * q_buses * c_rows))
+    n_mb = -(-lanes // max(128, lane_cap // 128 * 128))
+    bm = _round_up(-(-lanes // n_mb), 128)
+    fixed = 3 * 4 * bt * bm  # output block ×2, partial scratch
+    per_cycle = 2 * 4 * q_buses * (bt * c_lanes + c_rows * bm)
+    n_jb = -(-nj // max(1, (_VMEM_BYTES - fixed) // per_cycle))
+    nj_blk = -(-nj // n_jb)
+    strip = 8
+    while bt % (2 * strip) == 0 and 2 * strip * bm <= _STRIP_ELEMS:
+        strip *= 2
+    return TilePlan(bt, bm, nj_blk, n_mb * bm,
+                    (-(-t // bt), n_mb, n_jb), strip)
+
+
+def _emu_kernel(a_ref, w_ref, *rest, q_buses: int, nj: int, nj_blk: int,
+                n_panels: int, sigma: float, shot: float,
+                adc_bits: int | None, amax: float, rows: int, strip: int,
+                noisy: bool):
+    """rest = [ids_ref, seed_ref]?, o_ref, part_ref.
+
+    One grid step: token block tb, lane block mb, bus cycles
+    [jb·nj_blk, jb·nj_blk + nj_blk) ∩ [0, NJ).  Each slot's (bt, bm) partial
+    is one MXU product into part_ref; its noise + ADC epilogue then runs
+    strip by strip and adds into the output block, which stays resident
+    across the cycle axis."""
     if noisy:
-        seed_ref = rest[idx]
-        idx += 1
-    o_ref = rest[idx]
-    acc_ref = rest[idx + 1]
-
+        ids_ref, seed_ref, o_ref, part_ref = rest
+    else:
+        o_ref, part_ref = rest
     tb = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+    jb = pl.program_id(2)
+    bt = part_ref.shape[0]
 
-    @pl.when(j == 0)
+    @pl.when(jb == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     if noisy:
         k0 = seed_ref[0].astype(jnp.uint32)
         k1 = seed_ref[1].astype(jnp.uint32)
-        # element id within the (T, rows) face of this slot: rows is the
-        # full bank height, so (t_global, r) is globally unique per slot
-        tt = jax.lax.broadcasted_iota(jnp.int32, (block_t, rows), 0)
-        rr = jax.lax.broadcasted_iota(jnp.int32, (block_t, rows), 1)
-        c1 = ((tb * block_t + tt) * rows + rr).astype(jnp.uint32)
+        # lane m holds output row m = i·rows + r: panel i and bank row r
+        panel = ids_ref[0:1, :]
+        bank_row = ids_ref[1:2, :]
 
-    g2 = gamma * gamma
-    for q in range(q_buses):
-        a = a_ref[q, 0].astype(jnp.float32)  # (block_t, cols)
-        delta = d_ref[0, q, 0].astype(jnp.float32)  # (rows, cols)
-        d2 = delta * delta
-        w = (d2 - g2) / (d2 + g2)  # Lorentzian BPD transfer
-        if has_mask:
-            w = w * mask_ref[q]  # fabrication-dead rings read 0
-        part = jax.lax.dot_general(
-            a, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    def slot_epilogue(slot):
         if noisy:
-            slot = j * q_buses + q  # panel index this (bus, cycle) executes
-            c0 = (i * (q_buses * nj) + slot).astype(jnp.uint32)
+            # counters as on the (T, rows) face of panel i's slot:
+            # c0 = i·(Q·NJ) + slot, c1 = t_global·rows + r
+            c0 = (panel * (q_buses * nj) + slot).astype(jnp.uint32)
             valid = (slot < n_panels).astype(jnp.float32)
-            part = _slot_noise(part, k0, k1, c0, c1, valid, sigma, shot)
-        part = _adc(part, adc_bits, amax)
-        acc_ref[...] += part
 
-    @pl.when(j == nj - 1)
-    def _done():
-        o_ref[...] = acc_ref[...]
+        def body(s, carry):
+            r0 = pl.multiple_of(s * strip, strip)
+            part = part_ref[pl.ds(r0, strip), :]
+            if noisy:
+                tt = jax.lax.broadcasted_iota(jnp.int32, part.shape, 0)
+                c1 = ((tb * bt + r0 + tt) * rows
+                      + bank_row).astype(jnp.uint32)
+                part = _slot_noise(part, k0, k1, c0, c1, valid, sigma, shot)
+            part = _adc(part, adc_bits, amax)
+            o_ref[pl.ds(r0, strip), :] += part
+            return carry
+
+        # unrolled, so that one strip's threefry overlaps the next one's
+        jax.lax.fori_loop(0, bt // strip, body, 0, unroll=True)
+
+    def cycle(jj, carry):
+        j = jb * nj_blk + jj
+
+        @pl.when(j < nj)
+        def _run():
+            # slots in cycle-major order j·Q + q, as the f32 sums always ran
+            for q in range(q_buses):
+                part_ref[...] = jax.lax.dot_general(
+                    a_ref[q, jj], w_ref[jj, q], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                slot_epilogue(j * q_buses + q)
+
+        return carry
+
+    jax.lax.fori_loop(0, nj_blk, cycle, 0)
 
 
 def emu_bank_product_pallas(a_t, delta_eff, dead_mask, *, n_panels: int,
@@ -208,48 +288,52 @@ def emu_bank_product_pallas(a_t, delta_eff, dead_mask, *, n_panels: int,
     """
     t, q_buses, nj, cols = a_t.shape
     nm, _q, rows, _nj, _c = delta_eff.shape
+    m = nm * rows
     noisy = sigma > 0.0 or shot > 0.0
     if noisy and seed is None:
         raise ValueError("noisy fused bank requires a PRNG seed")
+    plan = tile_plan(t, q_buses, nj, cols, m, block_t)
+    bt, bm, nj_blk, m_pad = plan.bt, plan.bm, plan.nj_blk, plan.m_pad
+    t_pad = plan.grid[0] * bt
+    nj_pad = plan.grid[2] * nj_blk
 
-    # TPU-friendly layouts: last two dims of every block are the big ones
-    a_k = jnp.moveaxis(a_t, 0, 2)  # (Q, NJ, T, C)
-    rem = (-t) % block_t
-    if rem:
-        a_k = jnp.pad(a_k, ((0, 0), (0, 0), (0, rem), (0, 0)))
-    t_pad = t + rem
-    bt = min(block_t, t_pad)
-    d_k = jnp.moveaxis(delta_eff, 2, 3)  # (nm, Q, NJ, rows, C)
+    # bus-tiled inputs (Q, NJ, T, C); the transfer per slot as (C, m_pad):
+    # the ring weights of every row panel side by side along the lanes
+    a_k = jnp.pad(jnp.moveaxis(a_t, 0, 2).astype(jnp.float32),
+                  ((0, 0), (0, nj_pad - nj), (0, t_pad - t), (0, 0)))
+    w = _ring_transfer(delta_eff.astype(jnp.float32), dead_mask, gamma)
+    w_k = jnp.pad(w.transpose(3, 1, 4, 0, 2).reshape(nj, q_buses, cols, m),
+                  ((0, nj_pad - nj), (0, 0), (0, 0), (0, m_pad - m)))
 
     in_specs = [
-        pl.BlockSpec((q_buses, 1, bt, cols), lambda tb, i, j: (0, j, tb, 0)),
-        pl.BlockSpec((1, q_buses, 1, rows, cols),
-                     lambda tb, i, j: (i, 0, j, 0, 0)),
+        pl.BlockSpec((q_buses, nj_blk, bt, cols),
+                     lambda tb, mb, jb: (0, jb, tb, 0)),
+        pl.BlockSpec((nj_blk, q_buses, cols, bm),
+                     lambda tb, mb, jb: (jb, 0, 0, mb)),
     ]
-    operands = [a_k, d_k]
-    if dead_mask is not None:
-        in_specs.append(pl.BlockSpec((q_buses, rows, cols),
-                                     lambda tb, i, j: (0, 0, 0)))
-        operands.append(dead_mask)
+    operands = [a_k, w_k]
     if noisy:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(jnp.asarray(seed, jnp.uint32).astype(jnp.int32))
+        lane = jnp.arange(m_pad, dtype=jnp.int32)
+        in_specs += [pl.BlockSpec((2, bm), lambda tb, mb, jb: (0, mb)),
+                     pl.BlockSpec(memory_space=pltpu.SMEM)]
+        operands += [jnp.stack([lane // rows, lane % rows]),
+                     jnp.asarray(seed, jnp.uint32).astype(jnp.int32)]
 
     kern = functools.partial(
-        _emu_kernel, q_buses=q_buses, nj=nj, n_panels=n_panels, gamma=gamma,
-        sigma=sigma, shot=shot, adc_bits=adc_bits, amax=amax, rows=rows,
-        block_t=bt, has_mask=dead_mask is not None, noisy=noisy)
+        _emu_kernel, q_buses=q_buses, nj=nj, nj_blk=nj_blk,
+        n_panels=n_panels, sigma=sigma, shot=shot, adc_bits=adc_bits,
+        amax=amax, rows=rows, strip=plan.strip, noisy=noisy)
 
-    # output row-panel major: a (bt, rows) block then spans the array's
-    # whole last dim, which Mosaic accepts for any bank height (a (bt, 50)
-    # block over a (T, nm*50) array is refused: 50 is not a lane multiple)
+    # a 3-D (1, T, m_pad) result keeps the call's signature the profile
+    # reader matches: (Q, NJ, T, C) in, (·, T, ·) out
     out = pl.pallas_call(
         kern,
-        grid=(t_pad // bt, nm, nj),
+        grid=plan.grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, bt, rows), lambda tb, i, j: (i, tb, 0)),
-        out_shape=jax.ShapeDtypeStruct((nm, t_pad, rows), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bt, rows), jnp.float32)],
+        out_specs=pl.BlockSpec((None, bt, bm),
+                               lambda tb, mb, jb: (0, tb, mb)),
+        out_shape=jax.ShapeDtypeStruct((1, t_pad, m_pad), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bt, bm), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -257,7 +341,7 @@ def emu_bank_product_pallas(a_t, delta_eff, dead_mask, *, n_panels: int,
         name="emu_bank",
         metadata={"kernel": "emu_bank"},
     )(*operands)
-    return jnp.moveaxis(out[:, :t], 0, 1).reshape(t, nm * rows)
+    return out[0, :t, :m]
 
 
 # ---------------------------------------------------------------------------
@@ -284,11 +368,7 @@ def emu_bank_product_xla(a_t, delta_eff, dead_mask, *, n_panels: int,
     if noisy and seed is None:
         raise ValueError("noisy fused bank requires a PRNG seed")
 
-    g2 = gamma * gamma
-    d2 = jnp.square(delta_eff)
-    w = (d2 - g2) / (d2 + g2)
-    if dead_mask is not None:
-        w = w * dead_mask[None, :, :, None, :]
+    w = _ring_transfer(delta_eff, dead_mask, gamma)
     n_slots = q_buses * nj
     m_pad = nm * rows
     # slot-major layouts: slot s = j·Q + q (cycle-major, matching the

@@ -4,6 +4,7 @@ unfused ``channel.bank_product`` chain, the pallas↔xla bit-stream contract,
 resolution), and the fused path through full training sessions."""
 
 import dataclasses
+import math
 import os
 
 import hypothesis
@@ -42,21 +43,73 @@ def _quiet(n_buses=1, failed_buses=(), dead=0.0, adc_bits=8):
 # noiseless bit-tolerance vs the unfused chain
 # ---------------------------------------------------------------------------
 
+# Pallas tilings that split the grid: a token block below T (T ragged to
+# 8), and a VMEM budget small enough to split the lanes into blocks and the
+# bus cycles into blocks that do not divide them (see test_tile_plan_*)
+_T_ABOVE_BLOCK = {"block_t": 32}
+_SPLIT_BLOCKS = {"block_t": 16, "vmem": 512 * 1024}
+
+
+def _tiling(monkeypatch, tiling):
+    """Apply a test tiling; -> the ``block_t`` keyword it asks for."""
+    tiling = tiling or {}
+    if "vmem" in tiling:
+        monkeypatch.setattr(emu_matmul, "_VMEM_BYTES", tiling["vmem"])
+    return {"block_t": tiling["block_t"]} if "block_t" in tiling else {}
+
+
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize(
-    "t,m,k,n_buses", [
-        (4, 50, 20, 1),     # exactly one bank panel
-        (7, 61, 83, 2),     # ragged in every dimension
-        (5, 61, 83, 5),     # panels not divisible by buses (idle slots)
-        (16, 130, 260, 4),  # multi-tile rows and cycles
+    "t,m,k,n_buses,tiling", [
+        # exactly one bank panel
+        pytest.param(4, 50, 20, 1, None, id="4-50-20-1"),
+        # ragged in every dimension
+        pytest.param(7, 61, 83, 2, None, id="7-61-83-2"),
+        # panels not divisible by buses (idle slots)
+        pytest.param(5, 61, 83, 5, None, id="5-61-83-5"),
+        # multi-tile rows and cycles
+        pytest.param(16, 130, 260, 4, None, id="16-130-260-4"),
+        # 500 lanes (not a multiple of 128); T = 37 over blocks of 32
+        pytest.param(37, 500, 100, 1, _T_ABOVE_BLOCK, id="t-above-block"),
+        # lane blocks; NJ = 5 in cycle blocks of 2; 13 panels on 15 slots
+        pytest.param(37, 300, 260, 3, _SPLIT_BLOCKS, id="split-blocks"),
     ])
-def test_fused_matches_unfused_noiseless(impl, t, m, k, n_buses):
+def test_fused_matches_unfused_noiseless(monkeypatch, impl, t, m, k, n_buses,
+                                         tiling):
     cfg = _quiet(n_buses=n_buses)
     a_n, b_n = _operands(t, m, k, cfg)
     ref = channel.bank_product(a_n, b_n, cfg, None)
-    out = emu_matmul.fused_bank_product(a_n, b_n, cfg, None, impl=impl)
+    out = emu_matmul.fused_bank_product(a_n, b_n, cfg, None, impl=impl,
+                                        **_tiling(monkeypatch, tiling))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_tile_plan_lane_dense_at_qwen_shape():
+    """qwen1.5-0.5b's DFA projection (8192 × 1024 × 1024, one bus, 50 × 20
+    bank): every row panel in one lane-dense tile, a few hundred grid
+    steps a call; MNIST's (64 × 10 × 800) runs as one step, unpadded."""
+    plan = emu_matmul.tile_plan(8192, 1, 52, 20, 21 * 50)
+    assert math.prod(plan.grid) < 1000
+    assert 21 * 50 / plan.m_pad >= 0.9 and plan.bm == plan.m_pad
+    mnist = emu_matmul.tile_plan(64, 1, 1, 20, 16 * 50)
+    assert mnist.grid == (1, 1, 1) and mnist.bt == 64
+
+
+def test_tile_plan_splits_what_the_budget_cannot_hold(monkeypatch):
+    """The parity cases' tilings split the grid as they say: T ragged over
+    token blocks, bank rows over lane blocks, NJ over cycle blocks that do
+    not divide it, and the epilogue over several strips."""
+    plan = emu_matmul.tile_plan(37, 1, 5, 20, 500, block_t=32)
+    assert plan.grid[0] == 2 and plan.m_pad == 512 and plan.bt // plan.strip > 1
+    monkeypatch.setattr(emu_matmul, "_VMEM_BYTES", _SPLIT_BLOCKS["vmem"])
+    plan = emu_matmul.tile_plan(37, 3, 5, 20, 300, block_t=16)
+    assert plan.grid[0] == 3 and plan.grid[1] > 1
+    assert plan.grid[2] > 1 and 5 % plan.nj_blk
+    # a decode step through a 151,936-wide head: lanes in blocks that fit
+    monkeypatch.undo()
+    plan = emu_matmul.tile_plan(8, 1, 52, 20, 3039 * 50)
+    assert plan.grid[1] > 1 and plan.bm % 128 == 0
 
 
 def test_fused_matches_unfused_failed_bus_and_dead_rings():
@@ -115,17 +168,32 @@ def test_fused_equivalence_fuzz(t, m, k, n_buses, adc_bits, dead):
 # noise: pallas↔xla bit-stream contract + σ accounting
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("shot", [0.0, 0.05])
-def test_pallas_and_xla_share_the_noise_stream(shot):
+@pytest.mark.parametrize(
+    "shot,t,m,k,n_buses,dead,tiling", [
+        pytest.param(0.0, 9, 73, 100, 2, 0.0, None, id="0.0"),
+        pytest.param(0.05, 9, 73, 100, 2, 0.0, None, id="0.05"),
+        # 500 lanes (not a multiple of 128); T = 37 over blocks of 32
+        pytest.param(0.05, 37, 500, 100, 1, 0.0, _T_ABOVE_BLOCK,
+                     id="t-above-block"),
+        # lane blocks; NJ = 5 in cycle blocks of 2; 13 panels on 15 slots
+        pytest.param(0.05, 37, 300, 260, 3, 0.0, _SPLIT_BLOCKS,
+                     id="split-blocks"),
+        # fabrication-dead rings under noise; 150 lanes
+        pytest.param(0.05, 9, 130, 150, 2, 0.1, None, id="dead-rings"),
+    ])
+def test_pallas_and_xla_share_the_noise_stream(monkeypatch, shot, t, m, k,
+                                               n_buses, dead, tiling):
     """Both impls draw from the same (key, slot, element) counters, so the
     noisy outputs agree to accumulation-order tolerance — not merely in
     distribution."""
     cfg = photonics.PhotonicConfig(
-        noise_std=0.202, n_buses=2,
-        mrr=mrr.MRRConfig(adc_bits=8, drift_sigma=0.0, shot_noise=shot))
-    a_n, b_n = _operands(9, 73, 100, cfg)
+        noise_std=0.202, n_buses=n_buses,
+        mrr=mrr.MRRConfig(adc_bits=8, drift_sigma=0.0, shot_noise=shot,
+                          dead_ring_rate=dead))
+    a_n, b_n = _operands(t, m, k, cfg)
     x = emu_matmul.fused_bank_product(a_n, b_n, cfg, KEY, impl="xla")
-    p = emu_matmul.fused_bank_product(a_n, b_n, cfg, KEY, impl="pallas")
+    p = emu_matmul.fused_bank_product(a_n, b_n, cfg, KEY, impl="pallas",
+                                      **_tiling(monkeypatch, tiling))
     np.testing.assert_allclose(np.asarray(x), np.asarray(p),
                                rtol=1e-5, atol=1e-5)
 

@@ -8,11 +8,14 @@ topology is described inside a fixture, so collecting this file loads no
 TPU library and a host that cannot describe it skips these tests.
 """
 
+import importlib.util
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
+from jax._src.lib import xla_client
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core import photonics
@@ -99,6 +102,44 @@ def test_emu_fused_bank_compiles(one_chip, preset):
 
     _assert_kernel(fn, _sds((T, K), one_chip), _sds((M, K), one_chip),
                    _sds((2,), one_chip, jnp.uint32))
+
+
+def _emu_bank_reader():
+    """The benchmark's signature match for the fused emu kernel's calls."""
+    path = Path(__file__).resolve().parents[1] / "perfbench/kernels/emu_bank.py"
+    spec = importlib.util.spec_from_file_location("perfbench_emu_bank", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("t,k,m", [
+    (8192, 1024, 1024),   # qwen1.5-0.5b's DFA projection, 8 × 1024 tokens
+    (64, 10, 800),        # the paper's MLP: a batch of 64, DFA error of 10
+    (8, 1024, 151936),    # a decode step through qwen's head: lane blocks
+])
+def test_emu_bank_calls_match_the_benchmark_signature(one_chip, t, k, m):
+    """Each compiled call of the fused emu kernel, printed with its operand
+    shapes as a profile's op text carries them, passes the benchmark's
+    ``match``: a call the match missed would leave the kernel's time and
+    roofline share unread."""
+    cfg = photonics.preset("emu_onchip")
+
+    def fn(a, b, key):
+        return emu_matmul.fused_bank_product(
+            a, b, cfg, jax.random.wrap_key_data(key), impl="pallas",
+            interpret=False)
+
+    compiled = jax.jit(fn).lower(
+        _sds((t, k), one_chip), _sds((m, k), one_chip),
+        _sds((2,), one_chip, jnp.uint32)).compile()
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = "\n".join(mod.to_string(opts) for mod in
+                     compiled.runtime_executable().hlo_modules())
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    match = _emu_bank_reader().match
+    assert calls and all(match(ln) for ln in calls)
 
 
 def test_projection_runs_per_shard_under_a_mesh(topo):
