@@ -40,6 +40,7 @@ from repro.algos import base
 from repro.core import feedback as fb_lib
 from repro.core import photonics
 from repro.dist.sharding import unshard_fsdp
+from repro.models.base import segment_stats
 from repro.utils import prng
 from repro.utils.tree import path_map
 
@@ -227,7 +228,7 @@ def embed_grads(model, params, cfg: DFAConfig, fwd, fb, rng):
 def _totals(fwd):
     aux_total = sum(fwd["auxes"].values()) if fwd["auxes"] else 0.0
     total = fwd["loss"] + aux_total
-    metrics = dict(fwd["metrics"])
+    metrics = {**fwd["metrics"], **segment_stats(fwd["saved"])}
     metrics["loss"] = total
     if fwd["auxes"]:
         metrics["aux_loss"] = aux_total

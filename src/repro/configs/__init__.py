@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned archs + the paper's own MLP."""
+"""Architecture registry: the assigned archs, Moonlight, and the paper's own MLP."""
 
 from __future__ import annotations
 
@@ -9,6 +9,7 @@ from repro.configs import (
     mamba2_130m,
     minicpm3_4b,
     mnist_mlp,
+    moonlight_16b_a3b,
     qwen1_5_0_5b,
     qwen2_moe_a2_7b,
     qwen3_1_7b,
@@ -24,6 +25,7 @@ _MODULES = [
     granite_8b,
     qwen2_moe_a2_7b,
     kimi_k2_1t_a32b,
+    moonlight_16b_a3b,
     mamba2_130m,
     internvl2_2b,
     recurrentgemma_9b,

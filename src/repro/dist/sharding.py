@@ -186,7 +186,6 @@ ACT_RULES: dict[str, tuple] = {
     "tape_lbsd": (None, _B, None, MODEL), # DFA tape: model-sharded feature
     "logits": (_B, None, MODEL),          # (B, S, V): vocab on model
     "delta_tm": (_B, MODEL),              # projected error (T, M)
-    "expert_ecd": (MODEL, None, None),    # MoE buffers (E, C, D)
 }
 
 

@@ -50,6 +50,9 @@ class SavedSegment:
 
     inputs: typing.Any  # (L, ...) leaves — input to each block
     extras: typing.Any = None  # shared across layers (positions, enc_out, …)
+    # scalar counters of the segment's forward (MoE routing), merged into
+    # the step's metrics
+    stats: dict | None = None
 
 
 class DFAModel(Module):
@@ -75,6 +78,12 @@ class DFAModel(Module):
         raise NotImplementedError(
             f"{type(self).__name__} declares no forward GEMM workload")
 
+    def counters(self) -> dict:
+        """{trace counter name: metric keys} of the counters the segments'
+        forward adds to the step's metrics (``SavedSegment.stats``),
+        charted by an observer at its drain."""
+        return {}
+
     # --- forward parts ---
     def embed(self, params, batch):
         raise NotImplementedError
@@ -94,11 +103,11 @@ class DFAModel(Module):
     def loss(self, params, batch):
         """Plain forward loss — used by the backprop baseline and eval."""
         x0 = self.embed(params, batch)
-        x_final, _, auxes = self.run_segments(params, x0)
+        x_final, saved, auxes = self.run_segments(params, x0)
         logits = self.head_logits(params, x_final, batch)
         loss, metrics = self.loss_from_logits(logits, batch)
         aux_total = sum(auxes.values()) if auxes else 0.0
-        metrics = dict(metrics)
+        metrics = {**metrics, **segment_stats(saved)}
         if auxes:
             metrics["aux_loss"] = aux_total
         return loss + aux_total, metrics
@@ -109,6 +118,11 @@ class DFAModel(Module):
         projection of the (flattened-leading) error to x0's feature dim."""
         delta = project_fn(e_tap, fb_embed)
         return delta.astype(x0.dtype).reshape(x0.shape)
+
+
+def segment_stats(saved: dict) -> dict:
+    """The counters of every segment's forward, as one flat dict."""
+    return {k: v for seg in saved.values() for k, v in (seg.stats or {}).items()}
 
 
 def cross_entropy_loss(logits, labels, *, mask=None, label_smoothing=0.0):

@@ -1,11 +1,13 @@
-"""Decoder-only transformer LM — the workhorse for 7 of the 10 assigned
+"""Decoder-only transformer LM — the workhorse for most of the registered
 architectures (qwen1.5 / qwen3 / granite / minicpm3-MLA / qwen2-moe /
-kimi-k2 / internvl2 backbone).
+moonlight / kimi-k2 / internvl2 backbone).
 
 Composable switches: GQA or MLA temporal mix, dense or MoE channel mix,
 qkv-bias, qk-norm, sliding window, optional vision-stub prefix.  Layers are
-scanned (stacked params) — HLO depth-independent; DFA sees one segment
-named "blocks".
+scanned (stacked params) — HLO depth-independent.  DFA sees one segment
+named "blocks"; with ``n_dense_layers`` (DeepSeek-V3's
+``first_k_dense_replace``) a segment "dense" of dense-FFN blocks comes
+first, and "blocks" holds the MoE layers after it.
 """
 
 from __future__ import annotations
@@ -23,6 +25,7 @@ from repro.nn.embeddings import Embedding
 from repro.nn.frontends import VisionFrontendStub
 from repro.nn.linear import GatedMLP, Linear
 from repro.nn.module import Module, named_key, stack_init
+from repro.nn.moe import COUNTERS as MOE_COUNTERS
 from repro.nn.moe import MoE
 from repro.nn.norms import RMSNorm
 
@@ -34,15 +37,16 @@ class MoESettings:
     d_ff_expert: int
     n_shared_experts: int = 0
     d_ff_shared: int | None = None
-    capacity_factor: float = 1.25
-    lb_weight: float = 0.01
+    experts_held: tuple[int, int] | None = None  # this chip's share (nn/moe.py)
+    scoring: str = "softmax"  # softmax | sigmoid (noaux_tc selection bias)
+    routed_scale: float = 1.0
+    lb_weight: float = 0.01  # softmax routing's auxiliary losses
     z_weight: float = 1e-3
-    dispatch: str = "einsum"  # einsum | gather (see nn/moe.py)
 
 
 @dataclasses.dataclass(frozen=True)
 class MLASettings:
-    q_lora_rank: int = 768
+    q_lora_rank: int | None = 768  # None: q projected straight from x
     kv_lora_rank: int = 256
     qk_nope_dim: int = 64
     qk_rope_dim: int = 32
@@ -58,7 +62,7 @@ class VisionSettings:
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     name: str
-    n_layers: int
+    n_layers: int  # all layers, the leading dense ones included
     d_model: int
     n_heads: int
     n_kv_heads: int
@@ -73,6 +77,8 @@ class TransformerConfig:
     moe: MoESettings | None = None
     mla: MLASettings | None = None
     vision: VisionSettings | None = None
+    # leading layers with a dense d_ff FFN ahead of the MoE layers
+    n_dense_layers: int = 0
     dtype: jnp.dtype = jnp.float32
     # attention chunking for long-sequence prefill
     q_chunk: int = 2048
@@ -90,6 +96,7 @@ class TransformerConfig:
 @dataclasses.dataclass(frozen=True)
 class DecoderBlock(Module):
     cfg: TransformerConfig
+    dense: bool = False  # a dense FFN even where the model has MoE
 
     def _attn(self):
         c = self.cfg
@@ -99,7 +106,8 @@ class DecoderBlock(Module):
                 d_model=c.d_model, n_heads=c.n_heads,
                 q_lora_rank=m.q_lora_rank, kv_lora_rank=m.kv_lora_rank,
                 qk_nope_dim=m.qk_nope_dim, qk_rope_dim=m.qk_rope_dim,
-                v_head_dim=m.v_head_dim, rope_theta=c.rope_theta, dtype=c.dtype,
+                v_head_dim=m.v_head_dim, rope_theta=c.rope_theta,
+                norm_eps=c.norm_eps, dtype=c.dtype,
             )
         return Attention(
             d_model=c.d_model, n_heads=c.n_heads, n_kv_heads=c.n_kv_heads,
@@ -107,18 +115,34 @@ class DecoderBlock(Module):
             rope_theta=c.rope_theta, window=c.window, dtype=c.dtype,
         )
 
+    @property
+    def is_moe(self) -> bool:
+        return self.cfg.moe is not None and not self.dense
+
     def _ffn(self):
         c = self.cfg
-        if c.moe is not None:
+        if self.is_moe:
             m = c.moe
             return MoE(
                 d_model=c.d_model, d_ff_expert=m.d_ff_expert,
                 n_experts=m.n_experts, top_k=m.top_k,
                 n_shared_experts=m.n_shared_experts, d_ff_shared=m.d_ff_shared,
-                capacity_factor=m.capacity_factor, dispatch=m.dispatch,
-                dtype=c.dtype,
+                experts_held=m.experts_held, scoring=m.scoring,
+                routed_scale=m.routed_scale, dtype=c.dtype,
             )
         return GatedMLP(c.d_model, c.d_ff, dtype=c.dtype)
+
+    def _channel_mix(self, params, h):
+        """-> (ffn(h), weighted aux loss, routing counters)."""
+        if not self.is_moe:
+            return self._ffn()(params, h), jnp.float32(0.0), {}
+        m = self.cfg.moe
+        h, aux = self._ffn()(params, h)
+        aux_loss = jnp.float32(0.0)
+        if "lb_loss" in aux:
+            aux_loss = m.lb_weight * aux["lb_loss"] + m.z_weight * aux["z_loss"]
+        stats = {f"moe_{k}": aux[k] for k in MOE_COUNTERS}
+        return h, aux_loss, stats
 
     def init(self, key):
         c = self.cfg
@@ -129,8 +153,8 @@ class DecoderBlock(Module):
             "ffn": self._ffn().init(named_key(key, "ffn")),
         }
 
-    def __call__(self, params, x, positions):
-        """-> (y, weighted_aux_loss)."""
+    def forward(self, params, x, positions):
+        """-> (y, weighted_aux_loss, routing counters ({} for dense))."""
         c = self.cfg
         norm = RMSNorm(c.d_model, c.norm_eps, c.dtype)
         h = norm(params["norm1"], x)
@@ -138,13 +162,13 @@ class DecoderBlock(Module):
                          q_chunk=c.q_chunk, k_chunk=c.k_chunk)
         x = x + h
         h = norm(params["norm2"], x)
-        if c.moe is not None:
-            h, aux = self._ffn()(params["ffn"], h)
-            aux_loss = c.moe.lb_weight * aux["lb_loss"] + c.moe.z_weight * aux["z_loss"]
-        else:
-            h = self._ffn()(params["ffn"], h)
-            aux_loss = jnp.float32(0.0)
+        h, aux_loss, stats = self._channel_mix(params["ffn"], h)
         y = annotate(x + h, "act_btd")
+        return y, aux_loss, stats
+
+    def __call__(self, params, x, positions):
+        """-> (y, weighted_aux_loss)."""
+        y, aux_loss, _ = self.forward(params, x, positions)
         return y, aux_loss
 
     # --- serving ---
@@ -157,28 +181,19 @@ class DecoderBlock(Module):
         h = norm(params["norm1"], x)
         h, cache = self._attn().decode(params["attn"], h, cache, cache_len)
         x = x + h
-        h = norm(params["norm2"], x)
-        if c.moe is not None:
-            h, _ = self._ffn()(params["ffn"], h)
-        else:
-            h = self._ffn()(params["ffn"], h)
+        h, _, _ = self._channel_mix(params["ffn"], norm(params["norm2"], x))
         return x + h, cache
 
     def prefill(self, params, x, cache, cache_len, n_valid):
         """Chunked multi-token cache fill: x (B, C, d).  Padded (invalid)
-        chunk positions still flow through the FFN — harmless for dense
-        blocks; under MoE they can contend for expert capacity, a serving
-        approximation the dense configs never see."""
+        chunk positions still flow through the FFN; the MoE drops no token,
+        so they change no valid position's output."""
         c = self.cfg
         norm = RMSNorm(c.d_model, c.norm_eps, c.dtype)
         h = norm(params["norm1"], x)
         h, cache = self._attn().prefill(params["attn"], h, cache, cache_len, n_valid)
         x = x + h
-        h = norm(params["norm2"], x)
-        if c.moe is not None:
-            h, _ = self._ffn()(params["ffn"], h)
-        else:
-            h = self._ffn()(params["ffn"], h)
+        h, _, _ = self._channel_mix(params["ffn"], norm(params["norm2"], x))
         return x + h, cache
 
 
@@ -190,6 +205,21 @@ class TransformerLM(DFAModel):
     def block(self) -> DecoderBlock:
         return DecoderBlock(self.cfg)
 
+    def stacks(self) -> tuple[tuple[str, DecoderBlock, int], ...]:
+        """(segment name, block, layers) of each scanned stack, in order:
+        the leading dense layers ("dense", where there are any), then
+        "blocks"."""
+        c = self.cfg
+        out = ((("dense", DecoderBlock(c, dense=True), c.n_dense_layers),)
+               if c.n_dense_layers else ())
+        return out + (("blocks", self.block, c.n_layers - c.n_dense_layers),)
+
+    def counters(self) -> dict:
+        if self.cfg.moe is None:
+            return {}
+        # the routing counters, averaged over the MoE layers
+        return {"moe": tuple(f"moe_{k}" for k in MOE_COUNTERS)}
+
     @property
     def d_tap(self) -> int:
         return self.cfg.d_model  # "hidden" tap (DESIGN.md §8.3)
@@ -197,13 +227,14 @@ class TransformerLM(DFAModel):
     def segment_specs(self):
         c = self.cfg
 
-        def apply(p, x, extras):
-            positions = extras
-            return self.block(p, x, positions)
+        def spec(name, block, n):
+            def apply(p, x, extras):
+                positions = extras
+                return block(p, x, positions)
 
-        return (
-            SegmentSpec("blocks", c.n_layers, c.d_model, apply),
-        )
+            return SegmentSpec(name, n, c.d_model, apply)
+
+        return tuple(spec(*s) for s in self.stacks())
 
     def init(self, key):
         c = self.cfg
@@ -212,14 +243,14 @@ class TransformerLM(DFAModel):
             embed["vision"] = VisionFrontendStub(c.vision.d_vision, c.d_model, c.dtype).init(
                 named_key(key, "vision")
             )
-        return {
-            "embed": embed,
-            "blocks": stack_init(self.block, named_key(key, "blocks"), c.n_layers),
-            "head": {
-                "norm": RMSNorm(c.d_model, c.norm_eps, c.dtype).init(named_key(key, "fnorm")),
-                "out": Linear(c.d_model, c.v_padded, dtype=c.dtype).init(named_key(key, "out")),
-            },
+        params = {"embed": embed}
+        for name, block, n in self.stacks():
+            params[name] = stack_init(block, named_key(key, name), n)
+        params["head"] = {
+            "norm": RMSNorm(c.d_model, c.norm_eps, c.dtype).init(named_key(key, "fnorm")),
+            "out": Linear(c.d_model, c.v_padded, dtype=c.dtype).init(named_key(key, "out")),
         }
+        return params
 
     def embed(self, params, batch):
         c = self.cfg
@@ -235,16 +266,21 @@ class TransformerLM(DFAModel):
     def run_segments(self, params, x0):
         b, s, _ = x0.shape
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        saved, auxes = {}, {}
+        x = x0
+        for name, block, _ in self.stacks():
+            def body(x, bp, block=block):
+                bp = unshard_fsdp(bp)  # per-layer ZeRO-3 gather inside the scan
+                y, aux, stats = block.forward(bp, x, positions)
+                return y, (x, aux, stats)
 
-        def body(x, bp):
-            bp = unshard_fsdp(bp)  # per-layer ZeRO-3 gather inside the scan
-            y, aux = self.block(bp, x, positions)
-            return y, (x, aux)
-
-        x_final, (inputs, auxes) = jax.lax.scan(body, x0, params["blocks"])
-        inputs = annotate(inputs, "tape_lbsd")  # model-sharded DFA tape
-        saved = {"blocks": SavedSegment(inputs=inputs, extras=positions)}
-        return x_final, saved, {"blocks": jnp.sum(auxes)}
+            x, (inputs, aux, stats) = jax.lax.scan(body, x, params[name])
+            inputs = annotate(inputs, "tape_lbsd")  # model-sharded DFA tape
+            # routing counters: the mean over the segment's layers
+            stats = {k: jnp.mean(v) for k, v in stats.items()} or None
+            saved[name] = SavedSegment(inputs=inputs, extras=positions, stats=stats)
+            auxes[name] = jnp.sum(aux)
+        return x, saved, auxes
 
     def head_logits(self, params, x_final, batch):
         del batch
@@ -262,24 +298,34 @@ class TransformerLM(DFAModel):
 
     # ---- serving ----------------------------------------------------------
     def init_caches(self, batch: int, max_len: int, dtype=None):
-        """Stacked per-layer caches (L leading axis)."""
-        cache = self.block.init_cache(batch, max_len, dtype)
-        return jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x[None], (self.cfg.n_layers,) + x.shape).copy(), cache
-        )
+        """Per-layer caches of each stack, stacked (L leading axis), by
+        segment name."""
+        caches = {}
+        for name, block, n in self.stacks():
+            cache = block.init_cache(batch, max_len, dtype)
+            caches[name] = jax.tree_util.tree_map(
+                lambda x, n=n: jnp.broadcast_to(x[None], (n,) + x.shape).copy(), cache)
+        return caches
+
+    def _serve_stacks(self, params, x, caches, layer_fn):
+        """Run ``layer_fn(block, bp, x, cache) -> (y, cache)`` over every
+        stack; -> (x, new caches)."""
+        new_caches = {}
+        for name, block, _ in self.stacks():
+            def body(x, xs, block=block):
+                bp, cache = xs
+                return layer_fn(block, unshard_fsdp(bp), x, cache)
+
+            x, new_caches[name] = jax.lax.scan(body, x, (params[name], caches[name]))
+        return x, new_caches
 
     def decode_step(self, params, token, caches, cache_len):
         """token: (B, 1) int. Returns (logits (B,1,V), new caches)."""
         c = self.cfg
         x = Embedding(c.v_padded, c.d_model, c.dtype)(params["embed"]["tok"], token)
-
-        def body(x, xs):
-            bp, cache = xs
-            bp = unshard_fsdp(bp)
-            y, new_cache = self.block.decode(bp, x, cache, cache_len)
-            return y, new_cache
-
-        x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        x, new_caches = self._serve_stacks(
+            params, x, caches,
+            lambda block, bp, x, cache: block.decode(bp, x, cache, cache_len))
         h = RMSNorm(c.d_model, c.norm_eps, c.dtype)(params["head"]["norm"], x)
         return self._head(params, h), new_caches
 
@@ -305,14 +351,9 @@ class TransformerLM(DFAModel):
         is NOT advanced here — the engine owns slot bookkeeping."""
         c = self.cfg
         x = Embedding(c.v_padded, c.d_model, c.dtype)(params["embed"]["tok"], tokens)
-
-        def body(x, xs):
-            bp, cache = xs
-            bp = unshard_fsdp(bp)
-            y, new_cache = self.block.prefill(bp, x, cache, cache_len, n_valid)
-            return y, new_cache
-
-        x, new_caches = jax.lax.scan(body, x, (params["blocks"], caches))
+        x, new_caches = self._serve_stacks(
+            params, x, caches,
+            lambda block, bp, x, cache: block.prefill(bp, x, cache, cache_len, n_valid))
         h = RMSNorm(c.d_model, c.norm_eps, c.dtype)(params["head"]["norm"], x)
         return self._head(params, h), new_caches
 
@@ -323,37 +364,42 @@ class TransformerLM(DFAModel):
         (+ shared) expert FFNs actually streamed per token."""
         c = self.cfg
         hd = c.head_dim or c.d_model // c.n_heads
-        per_layer = []
+        attn = []
         if c.mla is not None:
             m = c.mla
-            per_layer += [
-                ("attn.q_down", m.q_lora_rank, c.d_model),
-                ("attn.q_up", c.n_heads * (m.qk_nope_dim + m.qk_rope_dim), m.q_lora_rank),
+            q_out = c.n_heads * (m.qk_nope_dim + m.qk_rope_dim)
+            if m.q_lora_rank is None:
+                attn.append(("attn.q", q_out, c.d_model))
+            else:
+                attn += [("attn.q_down", m.q_lora_rank, c.d_model),
+                         ("attn.q_up", q_out, m.q_lora_rank)]
+            attn += [
                 ("attn.kv_down", m.kv_lora_rank + m.qk_rope_dim, c.d_model),
                 ("attn.o", c.d_model, c.n_heads * m.v_head_dim),
             ]
         else:
-            per_layer += [
+            attn += [
                 ("attn.q", c.n_heads * hd, c.d_model),
                 ("attn.k", c.n_kv_heads * hd, c.d_model),
                 ("attn.v", c.n_kv_heads * hd, c.d_model),
                 ("attn.o", c.d_model, c.n_heads * hd),
             ]
-        if c.moe is not None:
-            mo = c.moe
-            ff = mo.top_k * mo.d_ff_expert
-            if mo.n_shared_experts:
-                ff += mo.n_shared_experts * (mo.d_ff_shared or mo.d_ff_expert)
-            per_layer.append(("ffn.router", mo.n_experts, c.d_model))
-        else:
-            ff = c.d_ff
-        per_layer += [
-            ("ffn.gate", ff, c.d_model),
-            ("ffn.up", ff, c.d_model),
-            ("ffn.down", c.d_model, ff),
-        ]
         specs = []
-        for i in range(c.n_layers):
-            specs += [(f"blocks[{i}].{n}", m, k) for (n, m, k) in per_layer]
+        for name, block, n in self.stacks():
+            per_layer = list(attn)
+            ff = c.d_ff
+            if block.is_moe:
+                mo = c.moe
+                ff = mo.top_k * mo.d_ff_expert
+                if mo.n_shared_experts:
+                    ff += mo.n_shared_experts * (mo.d_ff_shared or mo.d_ff_expert)
+                per_layer.append(("ffn.router", mo.n_experts, c.d_model))
+            per_layer += [
+                ("ffn.gate", ff, c.d_model),
+                ("ffn.up", ff, c.d_model),
+                ("ffn.down", c.d_model, ff),
+            ]
+            for i in range(n):
+                specs += [(f"{name}[{i}].{g}", m, k) for (g, m, k) in per_layer]
         specs.append(("head.unembed", c.v_padded, c.d_model))
         return specs

@@ -19,6 +19,7 @@ keeps a compressed latent cache and uses the absorbed form at decode time.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -118,7 +119,6 @@ def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
 
     n_q = sq // q_chunk
     n_k = skv // k_chunk
-    out_chunks = []
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
@@ -127,7 +127,7 @@ def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
     # see K chunks whose start position <= max q_pos in chunk j.  With the
     # standard layouts used here (prefill: q_pos == kv_pos; training:
     # both are arange) chunk ranges below are exact.
-    for j in range(n_q):
+    def q_chunk_out(j, qf, kf, vf, q_pos, kv_pos):
         qj = jax.lax.dynamic_slice_in_dim(qf, j * q_chunk, q_chunk, axis=1)
         qpj = jax.lax.dynamic_slice_in_dim(q_pos, j * q_chunk, q_chunk, axis=1)
         if causal and sq == skv and q_chunk % k_chunk == 0:
@@ -146,6 +146,9 @@ def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
         v_steps = v_slab.reshape(b, n_steps, k_chunk, h, d).transpose(1, 0, 2, 3, 4)
         kp_steps = kp_slab.reshape(b, n_steps, k_chunk).transpose(1, 0, 2)
 
+        # rematerialised in the vjp: a (q chunk, k chunk) tile's scores
+        # are recomputed from the carry rather than saved
+        @functools.partial(jax.checkpoint, prevent_cse=False)
         def body(carry, xs):
             acc, m_p, l_p = carry
             k_c, v_c, kp_c = xs
@@ -158,8 +161,16 @@ def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
         m0 = jnp.full((b, h, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, h, q_chunk), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0), (k_steps, v_steps, kp_steps))
-        outj = acc / jnp.maximum(jnp.transpose(l, (0, 2, 1))[..., None], 1e-30)
-        out_chunks.append(outj)
+        return acc / jnp.maximum(jnp.transpose(l, (0, 2, 1))[..., None], 1e-30)
+
+    # each q chunk is rematerialised whole in the vjp too: a backward keeps
+    # q, k and v, and holds one chunk's per-step carries at a time, so it
+    # needs O(S·d) memory where autodiff of the loops needs O(S²)
+    out_chunks = [
+        jax.checkpoint(functools.partial(q_chunk_out, j), prevent_cse=False)(
+            qf, kf, vf, q_pos, kv_pos)
+        for j in range(n_q)
+    ]
     return jnp.concatenate(out_chunks, axis=1).astype(q.dtype)
 
 
@@ -386,7 +397,8 @@ class MLAttention(Module):
     """Multi-head Latent Attention (MiniCPM3 / DeepSeek-V2 style).
 
     Projections:
-      q:  x → q_lora → (per head) [nope | rope]
+      q:  x → q_lora → (per head) [nope | rope]; with ``q_lora_rank`` None
+          (Moonlight / DeepSeek-V2-Lite) q comes straight from x
       kv: x → (kv_lora ‖ shared rope key)
           kv_lora → (per head) [k_nope | v]
     Cache stores only (kv_lora, k_rope): (r_kv + r_rope) floats/token.
@@ -396,12 +408,13 @@ class MLAttention(Module):
 
     d_model: int
     n_heads: int
-    q_lora_rank: int = 768
+    q_lora_rank: int | None = 768
     kv_lora_rank: int = 256
     qk_nope_dim: int = 64
     qk_rope_dim: int = 32
     v_head_dim: int = 64
     rope_theta: float = 10000.0
+    norm_eps: float = 1e-6  # the latents' RMSNorms
     dtype: jnp.dtype = jnp.float32
 
     @property
@@ -411,10 +424,16 @@ class MLAttention(Module):
     def init(self, key):
         mk = lambda n, i, o: Linear(i, o, dtype=self.dtype).init(named_key(key, n))
         h = self.n_heads
+        if self.q_lora_rank is None:
+            q = {"q": mk("q", self.d_model, h * self.qk_dim)}
+        else:
+            q = {
+                "q_down": mk("q_down", self.d_model, self.q_lora_rank),
+                "q_norm_scale": jnp.ones((self.q_lora_rank,), self.dtype),
+                "q_up": mk("q_up", self.q_lora_rank, h * self.qk_dim),
+            }
         return {
-            "q_down": mk("q_down", self.d_model, self.q_lora_rank),
-            "q_norm_scale": jnp.ones((self.q_lora_rank,), self.dtype),
-            "q_up": mk("q_up", self.q_lora_rank, h * self.qk_dim),
+            **q,
             "kv_down": mk("kv_down", self.d_model, self.kv_lora_rank + self.qk_rope_dim),
             "kv_norm_scale": jnp.ones((self.kv_lora_rank,), self.dtype),
             "k_up": mk("k_up", self.kv_lora_rank, h * self.qk_nope_dim),
@@ -426,11 +445,16 @@ class MLAttention(Module):
         """Return (q (B,S,H,qk_dim), c_kv (B,S,r), k_rope (B,S,rope))."""
         b, s, _ = x.shape
         h = self.n_heads
-        ql = forward_matmul(x, params["q_down"]["w"])
-        ql = rms_normalize(ql) * params["q_norm_scale"]
-        q = forward_matmul(ql, params["q_up"]["w"]).reshape(b, s, h, self.qk_dim)
+        if self.q_lora_rank is None:
+            q = forward_matmul(x, params["q"]["w"])
+        else:
+            ql = forward_matmul(x, params["q_down"]["w"])
+            ql = rms_normalize(ql, self.norm_eps) * params["q_norm_scale"]
+            q = forward_matmul(ql, params["q_up"]["w"])
+        q = q.reshape(b, s, h, self.qk_dim)
         kv = forward_matmul(x, params["kv_down"]["w"])
-        c_kv = rms_normalize(kv[..., : self.kv_lora_rank]) * params["kv_norm_scale"]
+        c_kv = (rms_normalize(kv[..., : self.kv_lora_rank], self.norm_eps)
+                * params["kv_norm_scale"])
         k_rope = kv[..., self.kv_lora_rank:]
         cos, sin = rotary_angles(positions, self.qk_rope_dim, self.rope_theta)
         q_nope, q_rope = q[..., : self.qk_nope_dim], q[..., self.qk_nope_dim:]

@@ -1,25 +1,103 @@
-"""Mixture-of-Experts FFN (qwen2-moe, kimi-k2).
+"""Mixture-of-Experts FFN (qwen2-moe, Moonlight/DeepSeek-V3, Kimi K2).
 
-Dispatch uses the GShard/MaxText one-hot capacity formulation so the expert
-computation is a single static einsum over the expert axis — GSPMD shards the
-expert dimension over the `model` mesh axis and turns dispatch/combine into
-all-to-alls (expert parallelism).  Token dropping beyond capacity follows
-position-in-expert order; shared experts (qwen2-moe: 4, kimi: 1) run densely.
+Routing is over all ``n_experts``; the layer computes only the experts it
+holds, ``experts_held = (lo, hi)``: the chip's share under expert
+parallelism (the whole layer where it is None).  What the experts held
+elsewhere add is left out, and the partial result goes on to the next
+layer, as it would ahead of the expert-parallel exchange.
 
-Aux losses: standard load-balancing loss (Switch) + router z-loss, returned
-so the trainer can weight them.
+* Router: float32 logits at ``highest`` precision, scored by ``softmax``
+  (qwen2-moe) or ``sigmoid`` (DeepSeek-V3's ``scoring_func`` with its
+  ``noaux_tc`` selection).  Sigmoid routing holds a per-expert bias
+  (``e_score_correction_bias``) that is added to the scores for selection
+  only: the weights are the selected scores without it, and its gradient
+  is stopped, so an optimizer leaves it as it is.  The top-k weights are
+  normalised (``norm_topk_prob``) and scaled by ``routed_scale``.
+* Dispatch drops no token: the (token, slot) assignments are sorted by
+  held expert (the others last), the rows are gathered into a static
+  buffer of T·top_k rows, and ``kernels.moe_gmm.grouped_matmul`` runs the
+  gate/up and down products over the held experts; its work follows the
+  rows actually routed here, and it leaves the rows past them unwritten.
+  The routed rows come back to (token, slot) order by the inverse
+  permutation (zeros for assignments held elsewhere) and are summed with
+  their weights.  Gathers both ways, in the forward and in the vjp, and
+  none reads a row past the routed ones.
+* Shared experts run densely on every token, once.
+
+Returns ``(y, aux)``: ``lb_loss`` and ``z_loss`` (Switch load balancing
+and router z-loss, for softmax routing; the block weights them), and
+three routing counters, ``routed_here`` (share of assignments on held
+experts), ``load_max_over_mean`` (over the held experts) and
+``rows_routed``.
+
+The expert products are digital under any photonic forward context: the
+grouped kernel does not go through ``photonics.forward_matmul``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import annotate
+from repro.kernels.moe_gmm import grouped_matmul
+from repro.nn import activations
 from repro.nn.linear import GatedMLP, Linear
-from repro.nn.module import Module, named_key, stack_init
+from repro.nn.module import Module, named_key
+
+# routing counters every MoE call returns in its aux
+COUNTERS = ("routed_here", "load_max_over_mean", "rows_routed")
+# the selection bias's initial spread: the published values are learned, and
+# random weights need a bias small against the scores' own spread
+BIAS_INIT_STD = 0.05
+
+
+def _rows_of(sorted_rows, inv, held):
+    """Each assignment's row of a sorted buffer, zeros for assignments held
+    elsewhere (whose rows the grouped kernel leaves unwritten)."""
+    idx = jnp.where(held, inv, sorted_rows.shape[0])
+    return sorted_rows.at[idx].get(mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, inv, held, k):
+    """Rows of x for the sorted assignments: x[order // k] (T·k, d).  Its
+    vjp gathers the held assignments' rows back by ``inv`` and sums each
+    token's k slots."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, held, k):
+    return _dispatch(x, order, inv, held, k), (inv, held)
+
+
+def _dispatch_bwd(k, res, g):
+    inv, held = res
+    return _rows_of(g, inv, held).reshape(-1, k, g.shape[-1]).sum(1), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _collect(out, order, inv, held):
+    """The sorted expert output back in (token, slot) order; its vjp
+    sorts the cotangent again, zero past the routed rows."""
+    return _rows_of(out, inv, held)
+
+
+def _collect_fwd(out, order, inv, held):
+    return _rows_of(out, inv, held), (order, held)
+
+
+def _collect_bwd(res, g):
+    order, held = res
+    return jnp.where(held[order][:, None], g[order], 0), None, None, None
+
+
+_collect.defvjp(_collect_fwd, _collect_bwd)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,163 +108,113 @@ class MoE(Module):
     top_k: int
     n_shared_experts: int = 0
     d_ff_shared: int | None = None  # defaults to d_ff_expert per shared expert
-    capacity_factor: float = 1.25
-    activation: str = "silu"
+    experts_held: tuple[int, int] | None = None  # [lo, hi); None: all
+    scoring: str = "softmax"  # softmax | sigmoid (with the selection bias)
     norm_topk_prob: bool = True
-    # tokens are routed in groups of this size (GShard-style scan): bounds
-    # the (T, E, C) dispatch tensor to O(group·E·cap_group) regardless of
-    # global batch — essential at kimi-k2 scale (1M tokens/step).
-    group_size: int = 4096
-    # dispatch implementation:
-    #   einsum — GShard one-hot matmuls (MXU-dense but ~3× the useful flops:
-    #            dispatch+combine each cost T·E·C·d ≈ the expert matmuls)
-    #   gather — slot-indexed gather/scatter: zero matmul flops for routing
-    dispatch: str = "einsum"
+    routed_scale: float = 1.0
+    activation: str = "silu"
     dtype: jnp.dtype = jnp.float32
 
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
+    def _expert_init(self, key):
+        return GatedMLP(self.d_model, self.d_ff_expert, self.activation,
+                        self.dtype).init(key)
+
     def init(self, key):
-        expert = GatedMLP(self.d_model, self.d_ff_expert, self.activation, self.dtype)
+        lo, hi = self.held
+        # one key per expert of the whole layer: a share holds the same
+        # weights as the uncut layer's experts lo..hi
+        keys = jax.random.split(named_key(key, "experts"), self.n_experts)[lo:hi]
         p = {
             "router": Linear(self.d_model, self.n_experts,
                              dtype=self.dtype).init(named_key(key, "router")),
-            "experts": stack_init(expert, named_key(key, "experts"), self.n_experts),
+            "experts": jax.vmap(self._expert_init)(keys),
         }
+        if self.scoring == "sigmoid":
+            p["router"]["bias"] = (jax.random.normal(
+                named_key(key, "select_bias"), (self.n_experts,))
+                * BIAS_INIT_STD).astype(self.dtype)
         if self.n_shared_experts:
-            d_sh = (self.d_ff_shared or self.d_ff_expert) * self.n_shared_experts
-            p["shared"] = GatedMLP(self.d_model, d_sh, self.activation,
+            p["shared"] = GatedMLP(self.d_model, self._d_shared, self.activation,
                                    self.dtype).init(named_key(key, "shared"))
         return p
 
-    def _route(self, params, x_flat):
-        """x_flat: (T, d). Returns (combine (T,E,C), dispatch (T,E,C), aux)."""
-        t = x_flat.shape[0]
-        e = self.n_experts
-        cap = max(1, int(self.capacity_factor * self.top_k * t / e))
-        logits = (x_flat @ params["router"]["w"]).astype(jnp.float32)  # (T, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        topv, topi = jax.lax.top_k(probs, self.top_k)  # (T, K)
+    @property
+    def _d_shared(self) -> int:
+        return (self.d_ff_shared or self.d_ff_expert) * self.n_shared_experts
+
+    def route(self, params, x_flat):
+        """x_flat (T, d) -> (logits (T, E), scores (T, E), experts (T, K),
+        weights (T, K))."""
+        logits = jnp.dot(x_flat.astype(jnp.float32),
+                         params["router"]["w"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        if self.scoring == "softmax":
+            scores = choice = jax.nn.softmax(logits, axis=-1)
+        elif self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            bias = jax.lax.stop_gradient(params["router"]["bias"]).astype(jnp.float32)
+            choice = scores + bias
+        else:
+            raise ValueError(f"unknown scoring {self.scoring!r}")
+        _, experts = jax.lax.top_k(choice, self.top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
         if self.norm_topk_prob:
-            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-        # one-hot expert assignment per k-slot: (T, K, E)
-        assign = jax.nn.one_hot(topi, e, dtype=jnp.float32)
-        # position of each (token, slot) within its expert queue
-        flat_assign = assign.reshape(t * self.top_k, e)
-        pos_in_expert = (jnp.cumsum(flat_assign, axis=0) - flat_assign).reshape(t, self.top_k, e)
-        keep = (pos_in_expert < cap).astype(jnp.float32) * assign
-        pos = jnp.einsum("tke,tke->tk", pos_in_expert, keep).astype(jnp.int32)  # (T, K)
-        pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32)  # (T, K, C)
-        dispatch = jnp.einsum("tke,tkc->tec", keep, pos_oh)  # (T, E, C) in {0,1}
-        combine = jnp.einsum("tk,tke,tkc->tec", topv, keep, pos_oh)
-        # aux losses
-        me = probs.mean(axis=0)  # (E,)
-        ce = assign.sum(axis=1).mean(axis=0)  # fraction routed per expert
-        lb_loss = e * jnp.sum(me * ce)
-        z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-        dropped = 1.0 - keep.sum() / (t * self.top_k)
-        aux = {"lb_loss": lb_loss, "z_loss": z_loss, "dropped_frac": dropped}
-        return combine, dispatch, aux
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        return logits, scores, experts, weights * self.routed_scale
 
-    def _route_topk(self, params, x_flat):
-        """Shared routing prelude: (topv (T,K), topi (T,K), keep, pos, cap, aux)."""
-        t = x_flat.shape[0]
-        e = self.n_experts
-        cap = max(1, int(self.capacity_factor * self.top_k * t / e))
-        logits = (x_flat @ params["router"]["w"]).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        topv, topi = jax.lax.top_k(probs, self.top_k)
-        if self.norm_topk_prob:
-            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-        assign = jax.nn.one_hot(topi, e, dtype=jnp.float32)
-        flat_assign = assign.reshape(t * self.top_k, e)
-        pos_in_expert = (jnp.cumsum(flat_assign, axis=0) - flat_assign).reshape(t, self.top_k, e)
-        keep = (pos_in_expert < cap).astype(jnp.float32) * assign
-        pos = jnp.einsum("tke,tke->tk", pos_in_expert, keep).astype(jnp.int32)
-        me = probs.mean(axis=0)
-        ce = assign.sum(axis=1).mean(axis=0)
-        aux = {
-            "lb_loss": e * jnp.sum(me * ce),
-            "z_loss": jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2),
-            "dropped_frac": 1.0 - keep.sum() / (t * self.top_k),
-        }
-        return topv, topi, keep, pos, cap, aux
-
-    def _group_forward(self, params, x_flat):
-        """Route+compute one token group. x_flat: (Tg, d) -> (y, aux)."""
-        if self.dispatch == "gather":
-            return self._group_forward_gather(params, x_flat)
-        combine, dispatch, aux = self._route(params, x_flat)
-        # dispatch tokens into per-expert buffers: (E, C, d)
-        expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(x_flat.dtype), x_flat)
-        expert_in = annotate(expert_in, "expert_ecd")
-        expert = GatedMLP(self.d_model, self.d_ff_expert, self.activation, self.dtype)
-        expert_out = jax.vmap(expert)(params["experts"], expert_in)  # (E, C, d)
-        expert_out = annotate(expert_out, "expert_ecd")
-        y = jnp.einsum("tec,ecd->td", combine.astype(x_flat.dtype), expert_out)
-        return y, aux
-
-    def _group_forward_gather(self, params, x_flat):
-        """Slot-indexed dispatch: scatter token ids into (E·C) slots, gather
-        token rows, run experts, gather slot outputs back per (token, k).
-        Identical routing/capacity semantics to the einsum path with zero
-        routing matmul flops."""
-        t, d = x_flat.shape
-        e = self.n_experts
-        topv, topi, keep, pos, cap, aux = self._route_topk(params, x_flat)
-        kept = keep.sum(-1) > 0  # (T, K) — this (token, k) slot was admitted
-        n_slots = e * cap
-        slot = topi * cap + pos  # (T, K)
-        slot = jnp.where(kept, slot, n_slots)  # dropped -> overflow slot
-        tok_ids = jnp.broadcast_to(jnp.arange(t)[:, None], slot.shape)
-        # slots are unique per (kept) (t, k) by construction of pos
-        slot_tok = jnp.zeros((n_slots + 1,), jnp.int32).at[slot.reshape(-1)].set(
-            tok_ids.reshape(-1).astype(jnp.int32), mode="drop")
-        slot_valid = jnp.zeros((n_slots + 1,), x_flat.dtype).at[slot.reshape(-1)].set(
-            1.0, mode="drop")
-        expert_in = x_flat[slot_tok[:n_slots]] * slot_valid[:n_slots, None]
-        expert_in = annotate(expert_in.reshape(e, cap, d), "expert_ecd")
-        expert = GatedMLP(self.d_model, self.d_ff_expert, self.activation, self.dtype)
-        expert_out = jax.vmap(expert)(params["experts"], expert_in)  # (E, C, d)
-        expert_out = annotate(expert_out, "expert_ecd")
-        out_flat = jnp.concatenate(
-            [expert_out.reshape(n_slots, d),
-             jnp.zeros((1, d), expert_out.dtype)], axis=0)
-        per_k = out_flat[slot]  # (T, K, d); overflow row is zeros
-        y = jnp.einsum("tk,tkd->td", topv.astype(per_k.dtype), per_k)
-        return y, aux
+    def _experts(self, params, x_flat, experts):
+        """The held experts' outputs for every assignment, in (token, slot)
+        order (T·K, d) — zeros for assignments held elsewhere — and the
+        rows each held expert received (H,)."""
+        lo, hi = self.held
+        n_held = hi - lo
+        k = self.top_k
+        flat = experts.reshape(-1)
+        held = (flat >= lo) & (flat < hi)
+        local = jnp.where(held, flat - lo, n_held)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        sizes = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :], axis=0,
+                        dtype=jnp.int32)
+        xs = _dispatch(x_flat, order, inv, held, k)
+        ew = params["experts"]
+        gate_up = jnp.concatenate([ew["gate"]["w"], ew["up"]["w"]], axis=-1)
+        h = grouped_matmul(xs, gate_up, sizes)
+        g, _ = activations.get(self.activation)
+        f = self.d_ff_expert
+        out = grouped_matmul(g(h[:, :f]) * h[:, f:], ew["down"]["w"], sizes)
+        return _collect(out, order, inv, held), sizes
 
     def __call__(self, params, x):
-        """x: (B, S, d) -> (y, aux).
-
-        Token groups are cut along the SEQUENCE axis ((B, chunk) tokens per
-        group) so the scanned group dim is never the batch-sharded dim —
-        scanning a sharded xs dim would force a full all-gather of the
-        activations in the scan (and its transpose in the vjp)."""
+        """x: (B, S, d) -> (y, aux)."""
         b, s, d = x.shape
         t = b * s
-        chunk = max(1, self.group_size // b)
-        if t <= self.group_size or s % chunk != 0:
-            y, aux = self._group_forward(params, x.reshape(t, d))
-        else:
-            g = s // chunk
-            xg = x.reshape(b, g, chunk, d).swapaxes(0, 1)  # (g, B, chunk, d)
-
-            # remat: the (Tg,E,C) dispatch/combine tensors are recomputed in
-            # the backward instead of being saved per group — without this
-            # the stacked routing residuals dominate peak memory at
-            # kimi-k2 scale (hundreds of GB/device)
-            @jax.checkpoint
-            def group_fwd(params, xt):
-                yt, auxt = self._group_forward(params, xt.reshape(b * chunk, d))
-                return yt.reshape(b, chunk, d), auxt
-
-            def body(_, xt):
-                return None, group_fwd(params, xt)
-
-            _, (y, auxes) = jax.lax.scan(body, None, xg)
-            y = y.swapaxes(0, 1).reshape(t, d)
-            aux = jax.tree_util.tree_map(jnp.mean, auxes)
-        if self.n_shared_experts:
-            d_sh = (self.d_ff_shared or self.d_ff_expert) * self.n_shared_experts
-            y = y + GatedMLP(self.d_model, d_sh, self.activation, self.dtype)(
-                params["shared"], x.reshape(t, d))
+        x_flat = x.reshape(t, d)
+        with jax.named_scope("moe.route"):
+            logits, scores, experts, weights = self.route(params, x_flat)
+        with jax.named_scope("moe.experts"):
+            per_slot, sizes = self._experts(params, x_flat, experts)
+        with jax.named_scope("moe.combine"):
+            per_slot = per_slot.reshape(t, self.top_k, d)
+            y = jnp.einsum("tk,tkd->td", weights.astype(per_slot.dtype), per_slot)
+            if self.n_shared_experts:
+                y = y + GatedMLP(self.d_model, self._d_shared, self.activation,
+                                 self.dtype)(params["shared"], x_flat)
+            y = y.astype(x.dtype)
+        rows = jnp.sum(sizes).astype(jnp.float32)
+        aux = {
+            "routed_here": rows / (t * self.top_k),
+            "load_max_over_mean": jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
+            "rows_routed": rows,
+        }
+        if self.scoring == "softmax":
+            e = self.n_experts
+            share = jnp.mean(jax.nn.one_hot(experts, e, dtype=jnp.float32).sum(1), 0)
+            aux["lb_loss"] = e * jnp.sum(jnp.mean(scores, 0) * share)
+            aux["z_loss"] = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
         return y.reshape(b, s, d), aux

@@ -126,14 +126,20 @@ class Observer:
         self.trace.counter(name, values)
 
     # ---- the per-logging-interval drain ----
-    def log_step(self, step, device_metrics) -> dict:
+    def log_step(self, step, device_metrics, counters=None) -> dict:
         """Drain one interval's device metrics (single batched
         ``device_get`` inside ``Registry.record``), run the hardware
         monitor and the anomaly detector over the host scalars, chart the
-        hw gauges as trace counters, and surface any new alert as a warn
-        instant.  Returns the host-side scalar dict (hw gauges and
-        anomaly flags merged in)."""
+        hw gauges and each of ``counters`` (``{name: metric keys}``, what
+        the model declares, e.g. an MoE's routing counters) as trace
+        counters, and surface any new alert as a warn instant.  Returns
+        the host-side scalar dict (hw gauges and anomaly flags merged
+        in)."""
         host = self.metrics.drain(device_metrics)
+        for name, keys in (counters or {}).items():
+            values = {k: host[k] for k in keys if k in host}
+            if values:
+                self.trace.counter(name, values, cat=name)
         if self.hwmon is not None:
             gauges = self.hwmon.sample(step, host)
             if gauges:
@@ -207,7 +213,7 @@ class NullObserver:
     def counter(self, name: str, values: dict) -> None:
         pass
 
-    def log_step(self, step, device_metrics) -> dict:
+    def log_step(self, step, device_metrics, counters=None) -> dict:
         return {}
 
     @property

@@ -395,7 +395,8 @@ class Trainer:
                     if (step + 1) % self.cfg.log_every == 0 or step + 1 == total_steps:
                         with observer.span("train.drain", step=step + 1):
                             if observer.enabled:
-                                host = observer.log_step(step + 1, metrics)
+                                host = observer.log_step(step + 1, metrics,
+                                                        self.model.counters())
                             else:
                                 # one batched transfer for the whole dict — never
                                 # one blocking float() per metric; the floats

@@ -81,7 +81,7 @@ def test_smoke_decode_step(name):
 
 
 def test_registry_complete():
-    assert len(configs.ASSIGNED) == 10
+    assert len(configs.ASSIGNED) == 11
     assert "mnist_mlp" in configs.list_archs()
     fams = {configs.get(n).family for n in configs.ASSIGNED}
     assert fams == {"dense", "moe", "ssm", "vlm", "hybrid", "audio"}
@@ -104,8 +104,10 @@ def test_full_configs_match_assignment():
         "minicpm3-4b": dict(n_layers=62, d_model=2560, n_heads=40, d_ff=6400,
                             vocab_size=73448),
         "qwen2-moe-a2.7b": dict(n_layers=24, d_model=2048, vocab_size=151936),
-        "kimi-k2-1t-a32b": dict(n_layers=61, d_model=7168, n_heads=64,
-                                n_kv_heads=8, vocab_size=163840),
+        "kimi-k2-1t-a32b": dict(n_layers=61, n_dense_layers=1, d_model=7168, n_heads=64,
+                                n_kv_heads=64, d_ff=18432, vocab_size=163840),
+        "moonlight-16b-a3b": dict(n_layers=6, n_dense_layers=1, d_model=2048, n_heads=16,
+                                  d_ff=11264, vocab_size=163840 // 8, norm_eps=1e-5),
         "internvl2-2b": dict(n_layers=24, d_model=2048, d_ff=8192, vocab_size=92553),
     }
     for name, want in specs.items():
@@ -114,8 +116,16 @@ def test_full_configs_match_assignment():
             assert getattr(cfg, k) == v, (name, k, getattr(cfg, k), v)
     moe = configs.get("qwen2-moe-a2.7b").make_model(jnp.bfloat16).cfg.moe
     assert (moe.n_experts, moe.top_k, moe.n_shared_experts) == (60, 4, 4)
-    kimi = configs.get("kimi-k2-1t-a32b").make_model(jnp.bfloat16).cfg.moe
-    assert (kimi.n_experts, kimi.top_k) == (384, 8)
+    kimi = configs.get("kimi-k2-1t-a32b").make_model(jnp.bfloat16).cfg
+    assert (kimi.mla.q_lora_rank, kimi.mla.kv_lora_rank, kimi.mla.qk_nope_dim,
+            kimi.mla.qk_rope_dim, kimi.mla.v_head_dim) == (1536, 512, 128, 64, 128)
+    assert (kimi.moe.n_experts, kimi.moe.top_k, kimi.moe.n_shared_experts,
+            kimi.moe.scoring, kimi.moe.routed_scale) == (384, 8, 1, "sigmoid", 2.827)
+    moon = configs.get("moonlight-16b-a3b").make_model(jnp.bfloat16).cfg
+    assert moon.mla.q_lora_rank is None and moon.mla.kv_lora_rank == 512
+    assert (moon.moe.n_experts, moon.moe.experts_held, moon.moe.top_k,
+            moon.moe.d_ff_expert, moon.moe.n_shared_experts, moon.moe.routed_scale) == \
+        (64, (0, 8), 6, 1408, 2, 2.446)
     rg = configs.get("recurrentgemma-9b").make_model(jnp.bfloat16).cfg
     assert (rg.n_layers, rg.d_model, rg.d_ff, rg.vocab_size, rg.window) == \
         (38, 4096, 12288, 256000, 2048)

@@ -12,27 +12,48 @@ from repro.train.optimizer import SGDM
 from repro.utils.tree import tree_allclose
 
 
+def _moe_dense(moe, p, x):
+    """The MoE written out: every expert densely on every token, weighted by
+    its normalised top-k softmax score (0 where not chosen)."""
+    xt = x.reshape(-1, x.shape[-1])
+    scores = jax.nn.softmax(xt @ p["router"]["w"], -1)
+    w, idx = jax.lax.top_k(scores, moe.top_k)
+    w = w / w.sum(-1, keepdims=True)
+    y = jnp.zeros_like(xt)
+    for e in range(moe.n_experts):
+        pe = jax.tree_util.tree_map(lambda a, e=e: a[e], p["experts"])
+        g = xt @ pe["gate"]["w"]
+        h = (g * jax.nn.sigmoid(g) * (xt @ pe["up"]["w"])) @ pe["down"]["w"]
+        y = y + jnp.sum(jnp.where(idx == e, w, 0.0), -1)[:, None] * h
+    return y.reshape(x.shape)
+
+
 def test_moe_gather_equals_einsum_dispatch():
-    kwargs = dict(d_model=16, d_ff_expert=32, n_experts=4, top_k=2,
-                  capacity_factor=8.0)
-    p = nn.MoE(**kwargs).init(jax.random.PRNGKey(0))
+    """The sorted dispatch through the grouped kernel equals the dense
+    per-expert formula, outputs and gradients."""
+    moe = nn.MoE(d_model=16, d_ff_expert=32, n_experts=4, top_k=2)
+    p = moe.init(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16))
-    y1, a1 = nn.MoE(dispatch="einsum", **kwargs)(p, x)
-    y2, a2 = nn.MoE(dispatch="gather", **kwargs)(p, x)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5, atol=1e-6)
-    for k in a1:
-        np.testing.assert_allclose(float(a1[k]), float(a2[k]), rtol=1e-5)
+    y1, _ = moe(p, x)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(_moe_dense(moe, p, x)),
+                               rtol=1e-5, atol=1e-6)
+    g1 = jax.grad(lambda pp: jnp.sum(moe(pp, x)[0] ** 2))(p)
+    g2 = jax.grad(lambda pp: jnp.sum(_moe_dense(moe, pp, x) ** 2))(p)
+    assert tree_allclose(g1, g2, rtol=1e-4, atol=1e-5)
 
 
 def test_moe_gather_equals_einsum_with_drops():
-    kwargs = dict(d_model=16, d_ff_expert=32, n_experts=4, top_k=2,
-                  capacity_factor=0.5)  # forces token dropping
-    p = nn.MoE(**kwargs).init(jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16))
-    y1, a1 = nn.MoE(dispatch="einsum", **kwargs)(p, x)
-    y2, a2 = nn.MoE(dispatch="gather", **kwargs)(p, x)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5, atol=1e-6)
-    assert float(a1["dropped_frac"]) == float(a2["dropped_frac"]) > 0
+    """Where a capacity used to drop tokens (every token on one expert), no
+    token is dropped: the layer still equals the dense formula."""
+    moe = nn.MoE(d_model=16, d_ff_expert=32, n_experts=4, top_k=2)
+    p = moe.init(jax.random.PRNGKey(0))
+    p["router"]["w"] = p["router"]["w"].at[:, 0].add(100.0)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16)))
+    y1, aux = moe(p, x)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(_moe_dense(moe, p, x)),
+                               rtol=1e-5, atol=1e-6)
+    assert float(aux["rows_routed"]) == 64.0
+    assert float(aux["load_max_over_mean"]) >= 2.0   # expert 0 takes every token
 
 
 def test_mamba_split_proj_decode_parity():

@@ -200,3 +200,42 @@ def test_kernel_name_in_op_text(one_chip, kernel):
         assert f'/{kernel}/pallas_call"' in op
         assert op.index(f'"kernel":"{kernel}"') > op.index("custom-call(")
         assert not [k for k in cases if k != kernel and f'"kernel":"{k}"' in op]
+
+
+def _moe_gmm_reader():
+    """The benchmark's match for the grouped expert matmul's calls."""
+    path = Path(__file__).resolve().parents[1] / "perfbench/kernels/moe_gmm.py"
+    spec = importlib.util.spec_from_file_location("perfbench_moe_gmm", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_moe_gmm_compiles_at_the_moonlight_cell_shape(one_chip, monkeypatch):
+    """The grouped expert matmul, its product and both vjp products, at the
+    Moonlight cell's shape (2 × 8192 tokens × top-6 = 98,304 buffer rows,
+    8 held experts of width 1408 at d=2048): each call compiles for the
+    chip, names itself in its op text, and passes the benchmark's match."""
+    from repro.kernels import moe_gmm
+
+    monkeypatch.setattr(moe_gmm, "_on_tpu", lambda: True)
+    m, d, f, g = 98304, 2048, 1408, 8
+
+    def fn(x, w1, w2, sizes, ct):
+        def layer(x, w1, w2):
+            h = moe_gmm.grouped_matmul(x, w1, sizes)
+            return moe_gmm.grouped_matmul(jax.nn.silu(h[:, :f]) * h[:, f:], w2, sizes)
+
+        y, vjp = jax.vjp(layer, x, w1, w2)
+        return y, vjp(ct)
+
+    text = _assert_kernel(fn, _sds((m, d), one_chip), _sds((g, d, 2 * f), one_chip),
+                          _sds((g, f, d), one_chip), _sds((g,), one_chip, jnp.int32),
+                          _sds((m, d), one_chip))
+    ops = re.split(r"\n(?= *(?:ROOT )?%)", text)
+    calls = [op.strip() for op in ops if 'custom_call_target="tpu_custom_call"' in op]
+    assert len(calls) == 6
+    names = sorted(re.search(r'"kernel":"(moe_gmm\w*)"', op).group(1) for op in calls)
+    assert names == ["moe_gmm"] * 2 + ["moe_gmm_dlhs"] * 2 + ["moe_gmm_drhs"] * 2
+    match = _moe_gmm_reader().match
+    assert all(match(op) for op in calls)
