@@ -28,13 +28,14 @@ Two implementations share the schedule and the PRNG bit-stream:
 
 Noise: the unfused path draws per-(bus,pass) thermal and shot noise with
 ``jax.random.normal`` over the materialised partial tensor.  Here the
-draws happen inside the kernel from an inlined threefry2x32 keyed by
-(key, slot, element) counters — both impls use the *same* counters, so
-pallas and xla noise is bit-identical — and idle padded slots are masked
-exactly like the unfused path, keeping ``noise_sigma_total``'s real-panel
-accounting (one draw per REAL contraction panel).  Against the unfused
-path the noise is statistically identical but not bit-identical (different
-PRNG stream); with noise off the two paths agree to f32 tolerance.
+draws happen inside the kernel from an inlined threefry2x32 of 13 rounds
+(``_NOISE_ROUNDS``; ``counter_gaussian`` says why 13) keyed by (key, slot,
+element) counters — both impls use the *same* counters, so pallas and xla
+noise is bit-identical — and idle padded slots are masked exactly like the
+unfused path, keeping ``noise_sigma_total``'s real-panel accounting (one
+draw per REAL contraction panel).  Against the unfused path the noise is
+statistically identical but not bit-identical (different PRNG stream);
+with noise off the two paths agree to f32 tolerance.
 
 Physics boundary: weight *inscription* (heater-DAC quantisation and the
 controller's Jacobi crosstalk pre-compensation) is control-plane work
@@ -71,21 +72,32 @@ def _rotl(x, r: int):
     return (x << jnp.uint32(r)) | (x >> jnp.uint32(32 - r))
 
 
-def threefry2x32(k0, k1, c0, c1):
-    """The Threefry-2x32 block cipher (20 rounds): (key, counter) -> two
-    independent uint32 words per counter.  Elementwise over broadcastable
-    uint32 inputs."""
+def threefry2x32(k0, k1, c0, c1, rounds: int = 20):
+    """The Threefry-2x32 block cipher: (key, counter) -> two independent
+    uint32 words per counter.  Elementwise over broadcastable uint32
+    inputs.
+
+    ``rounds`` (static) stops the standard schedule early: round n rotates
+    by ``_ROTATIONS[(n // 4) % 2][n % 4]`` and a key injection follows
+    every 4th round.  The default 20 is the standard cipher (JAX's own
+    ``threefry_2x32``); 13 runs the first 13 rounds, with injections after
+    rounds 4, 8 and 12."""
     ks = (k0, k1, k0 ^ k1 ^ jnp.uint32(0x1BD11BDA))
     x0 = c0 + ks[0]
     x1 = c1 + ks[1]
-    for i in range(5):
-        for r in _ROTATIONS[i % 2]:
-            x0 = x0 + x1
-            x1 = _rotl(x1, r)
-            x1 = x1 ^ x0
-        x0 = x0 + ks[(i + 1) % 3]
-        x1 = x1 + ks[(i + 2) % 3] + jnp.uint32(i + 1)
+    for n in range(rounds):
+        x0 = x0 + x1
+        x1 = _rotl(x1, _ROTATIONS[(n // 4) % 2][n % 4])
+        x1 = x1 ^ x0
+        if n % 4 == 3:
+            i = n // 4
+            x0 = x0 + ks[(i + 1) % 3]
+            x1 = x1 + ks[(i + 2) % 3] + jnp.uint32(i + 1)
     return x0, x1
+
+
+# threefry rounds behind the kernel's noise (counter_gaussian)
+_NOISE_ROUNDS = 13
 
 
 # Irwin–Hall(4) scale: sum of four 16-bit uniforms has variance
@@ -98,7 +110,8 @@ _SHOT_STREAM = 0x80000000
 
 def counter_gaussian(k0, k1, c0, c1):
     """One standard gaussian per counter: the four 16-bit lanes of the two
-    threefry words summed (Irwin–Hall n=4) and rescaled to unit variance.
+    words of ``_NOISE_ROUNDS``-round threefry summed (Irwin–Hall n=4) and
+    rescaled to unit variance.
 
     Exact mean 0 and variance 1 − 2.3e-10; tails truncate at ±2√3 σ —
     far beyond anything the per-pass ADC resolves, and well inside the
@@ -106,8 +119,18 @@ def counter_gaussian(k0, k1, c0, c1):
     Box–Muller deliberately: no transcendentals, so it runs inside the
     Pallas kernel without lowering surprises and costs ~an order of
     magnitude less than ``log``+``cos`` over the full partial tensor on
-    CPU hosts."""
-    b0, b1 = threefry2x32(k0, k1, c0, c1)
+    CPU hosts.
+
+    The cipher runs ``_NOISE_ROUNDS`` = 13 rounds, not the standard 20:
+    the reduced-round Threefry-2x32 that its authors report
+    Crush-resistant (Salmon et al., "Parallel random numbers: as easy as
+    1, 2, 3", SC'11, Table 2; 20 is their margin for general use), at 76
+    integer ops a counter against 119, in a kernel whose pace the
+    generator sets.  ``tests/test_emu_kernel.py`` checks the stream over
+    a (slot × element) grid laid out as the kernel's counters:
+    neighbouring-element, neighbouring-slot and thermal-vs-shot
+    correlation and the top byte's χ², which 4, 6 and 8 rounds fail."""
+    b0, b1 = threefry2x32(k0, k1, c0, c1, _NOISE_ROUNDS)
     m = jnp.uint32(0xFFFF)
     s = ((b0 & m) + (b0 >> jnp.uint32(16))
          + (b1 & m) + (b1 >> jnp.uint32(16)))

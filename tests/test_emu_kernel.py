@@ -250,6 +250,79 @@ def test_shot_stream_is_distinct():
     assert abs(corr) < 0.05
 
 
+def test_threefry_at_20_rounds_is_jax_threefry():
+    """The full-round schedule is the standard cipher: JAX's own
+    Threefry-2x32 on the same keys and counters, word for word."""
+    from jax._src import prng
+    count = jnp.arange(4096, dtype=jnp.uint32)
+    for k0, k1 in [(0, 0), (3, 5), (0x13198A2E, 0x03707344)]:
+        key = jnp.array([k0, k1], jnp.uint32)
+        x0, x1 = emu_matmul.threefry2x32(key[0], key[1], count[:2048],
+                                         count[2048:], rounds=20)
+        np.testing.assert_array_equal(
+            np.concatenate([np.asarray(x0), np.asarray(x1)]),
+            np.asarray(prng.threefry_2x32(key, count)))
+
+
+# 256-bin χ² (255 degrees of freedom) above this has p < 1e-4
+_CHI2_LIMIT = 350.0
+_STREAM_KEYS = [(0, 0), (3, 5), (0x13198A2E, 0x03707344),
+                (0xDEADBEEF, 0x12345678)]
+
+
+def _stream_defects(rounds):
+    """Statistics of ``counter_gaussian`` at ``rounds`` over a 1024-slot ×
+    1024-element counter grid, laid out as the kernel's (c0 = slot,
+    c1 = element): -> the checks that fail, over a few keys."""
+    defects = set()
+
+    def corr(a, b):
+        a, b = a - a.mean(), b - b.mean()
+        return float((a * b).mean() / jnp.sqrt((a * a).mean()
+                                               * (b * b).mean()))
+
+    @jax.jit
+    def draw(k0, k1):
+        c0 = jax.lax.broadcasted_iota(jnp.uint32, (1024, 1024), 0)
+        c1 = jax.lax.broadcasted_iota(jnp.uint32, (1024, 1024), 1)
+        z = emu_matmul.counter_gaussian(k0, k1, c0, c1)
+        z_shot = emu_matmul.counter_gaussian(
+            k0, k1, c0 ^ jnp.uint32(emu_matmul._SHOT_STREAM), c1)
+        top = jnp.stack(emu_matmul.threefry2x32(k0, k1, c0, c1, rounds))
+        hist = jnp.bincount((top >> 24).astype(jnp.int32).ravel(),
+                            length=256)
+        return z, z_shot, hist
+
+    for k0, k1 in _STREAM_KEYS:
+        z, z_shot, hist = draw(jnp.uint32(k0), jnp.uint32(k1))
+        if abs(corr(z[:, :-1], z[:, 1:])) >= 0.005:
+            defects.add("neighbouring element")
+        if abs(corr(z[:-1], z[1:])) >= 0.005:
+            defects.add("neighbouring slot")
+        if abs(corr(z, z_shot)) >= 0.005:
+            defects.add("thermal vs shot")
+        expected = float(hist.sum()) / 256
+        if float(jnp.sum((hist - expected) ** 2) / expected) >= _CHI2_LIMIT:
+            defects.add("top byte chi2")
+    return defects
+
+
+@pytest.mark.parametrize("rounds,sound", [
+    pytest.param(emu_matmul._NOISE_ROUNDS, True, id="noise-rounds"),
+    # negative controls: the same checks see reduced-round streams fail
+    pytest.param(8, False, id="8-rounds"),
+    pytest.param(6, False, id="6-rounds"),
+    pytest.param(4, False, id="4-rounds"),
+])
+def test_noise_stream_statistics(monkeypatch, rounds, sound):
+    """The kernel's reduced-round noise stream shows no correlation
+    between neighbouring elements, neighbouring slots or the thermal and
+    shot streams, and a flat top byte; fewer rounds fail these checks."""
+    monkeypatch.setattr(emu_matmul, "_NOISE_ROUNDS", rounds)
+    defects = _stream_defects(rounds)
+    assert (not defects) if sound else defects, defects
+
+
 # ---------------------------------------------------------------------------
 # the emu_kernel seam: resolution, env override, session plumbing
 # ---------------------------------------------------------------------------
