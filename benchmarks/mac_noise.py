@@ -31,7 +31,7 @@ def run(n: int = 3900, seed: int = 0):
         err = np.asarray(outs - a @ b.T).ravel()
         meas_std = float(err.std())
         scale = float(jnp.max(jnp.abs(a)) * jnp.max(jnp.abs(b)))
-        meas_bits = photonics.std_to_bits(meas_std / scale)
+        meas_bits = photonics.sigma_to_resolution(meas_std / scale)
         rows.append({
             "preset": preset, "paper_sigma": sigma, "paper_bits": bits,
             "measured_sigma": meas_std, "measured_bits": meas_bits,

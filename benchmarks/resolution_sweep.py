@@ -20,7 +20,7 @@ def run(bits_list=(2.0, 3.0, 3.31, 4.35, 6.0, 8.0), train_n=6144, test_n=1536,
     xte, yte = data["test"]
     rows = []
     for bits in bits_list:
-        cfg = photonics.PhotonicConfig(noise_std=photonics.bits_to_std(bits))
+        cfg = photonics.PhotonicConfig(noise_std=photonics.resolution_to_sigma(bits))
         pipe = pipeline.ArrayClassification(xtr, ytr, batch_size=64, seed=seed)
         session = api.build_session(
             arch=MLPClassifier(hidden=hidden), algo="dfa", hardware=cfg,

@@ -183,20 +183,17 @@ def phase_kernels(clock):
     from repro.kernels import emu_matmul, ops
 
     t0 = time.monotonic()
-    ka, kb, km = jax.random.split(jax.random.PRNGKey(0), 3)
+    ka, kb = jax.random.split(jax.random.PRNGKey(0))
     a = jax.random.normal(ka, (256, 384), jnp.float32)
     b = jax.random.normal(kb, (200, 384), jnp.float32)
-    mask = (jax.random.uniform(km, (256, 200)) > 0.5).astype(jnp.float32)
     ideal = photonics.preset("ideal")
     with jax.default_matmul_precision("highest"):
         ref = photonics.photonic_matmul(a, b, ideal)
-        ref_masked = ref * mask
         emu_cfg = photonics.preset("emu_ideal")
         a_n, b_n, _, _ = photonics.normalise_operands(a, b, emu_cfg)
         emu_ref = channel.bank_product(a_n, b_n, emu_cfg)
     got = {
         "photonic_matmul": (ops.photonic_matmul(a, b, ideal), ref),
-        "dfa_gradient": (ops.dfa_gradient(a, b, mask, ideal), ref_masked),
         "emu_bank": (emu_matmul.fused_bank_product(a_n, b_n, emu_cfg,
                                                    impl="pallas"), emu_ref),
     }

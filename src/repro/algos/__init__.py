@@ -15,7 +15,6 @@ Built-in registrations (import side effect of the submodules below):
 
 * ``bp``            — exact backprop baseline (algos/bp.py)
 * ``dfa``           — the paper's Eq. 1 engine (algos/dfa.py)
-* ``dfa-fused``     — same gradients, update fused into the backward map
 * ``dfa-layerwise`` — per-layer error tap, the shallow-DFA ablation
 
 The hardware axis is ``core.photonics.PRESETS`` and the execution axis is

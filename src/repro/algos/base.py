@@ -18,10 +18,6 @@ Contract:
 * ``value_and_grad(model, cfg)`` — returns
   ``fn(params, extra, batch, rng) -> ((loss, metrics), grads)`` with
   ``grads`` matching ``params``'s structure.  Pure; jit-able.
-* ``fused_step(model, cfg, optimizer)`` — optional memory-optimised
-  step ``(params, extra, opt_state, batch, rng) -> (params', opt_state',
-  loss)``.  The base class provides a generic compose-with-optimizer
-  fallback so only algorithms with a genuinely fused path override it.
 
 ``cfg`` is the algorithm config (algos.dfa.DFAConfig for the whole DFA
 family; BP ignores it).  Keeping one config type across the family lets the
@@ -32,8 +28,8 @@ from __future__ import annotations
 
 
 class Algorithm:
-    """Base class: subclasses override value_and_grad (and optionally the
-    rest); instances are registered by name in repro.algos."""
+    """Base class: subclasses override value_and_grad (and optionally
+    init_extra_state); instances are registered by name in repro.algos."""
 
     name = "base"
 
@@ -44,18 +40,6 @@ class Algorithm:
 
     def value_and_grad(self, model, cfg):
         raise NotImplementedError
-
-    def fused_step(self, model, cfg, optimizer):
-        """Generic fallback: value_and_grad composed with optimizer.update.
-        Algorithms with a real fused path (dfa-fused) override this."""
-        vg = self.value_and_grad(model, cfg)
-
-        def step(params, extra, opt_state, batch, rng):
-            (loss, _metrics), grads = vg(params, extra, batch, rng)
-            new_params, new_opt, _info = optimizer.update(grads, opt_state, params)
-            return new_params, new_opt, loss
-
-        return step
 
 
 _REGISTRY: dict[str, Algorithm] = {}

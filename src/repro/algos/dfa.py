@@ -21,12 +21,7 @@ Error compression (`ternary` per the paper's ref [48], or `int8`) is applied
 to e before projection/broadcast — the gradient-compression knob for
 distributed training.
 
-This module registers two algorithms:
-
-* ``dfa``       — value_and_grad per Eq. 1 (+ the generic fused fallback)
-* ``dfa-fused`` — same gradients, but ``fused_step`` consumes each layer's
-  gradient immediately inside the backward map (SGDM fused into the layer
-  loop) so stacked segment gradients never materialise.
+This module registers the ``dfa`` algorithm: value_and_grad per Eq. 1.
 """
 
 from __future__ import annotations
@@ -59,8 +54,6 @@ class DFAConfig:
     # photonic execution backend: auto | ref | pallas | a PhotonicBackend
     # instance (see core.photonics.register_backend / get_backend)
     backend: str | photonics.PhotonicBackend = "auto"
-    sequential: bool = False  # lax.map (False: still sequential in schedule,
-    # but dependency-free; kept for clarity/ablation hooks)
     # Freeze norm scales in DFA blocks.  The cotangent at each norm output
     # exists ONLY to produce the norm-scale gradient (DFA discards input
     # cotangents), yet it costs a (B,S,D) model-axis all-reduce per matmul
@@ -256,86 +249,6 @@ def value_and_grad(model, cfg: DFAConfig):
     return fn
 
 
-def make_fused_train_step(model, cfg: DFAConfig, optimizer):
-    """DFA backward with the SGD-momentum update FUSED into the per-layer
-    map: each layer's gradient is consumed immediately by its parameter /
-    momentum update, so the stacked segment gradients never materialise
-    (at kimi-k2 scale that is ~8 GB/device of peak memory).  This is only
-    possible because the DFA backward has no inter-layer dependency — the
-    update can't invalidate any later backward step.
-
-    optimizer must be SGDM-shaped (lr, momentum, weight_decay fields).
-    Returns step(params, fb, opt_state, batch, rng) ->
-    (new_params, new_opt_state, loss).
-    """
-    specs = model.segment_specs()
-
-    def _upd(p, m, g, lr):
-        g32 = g.astype(jnp.float32)
-        if optimizer.weight_decay:
-            g32 = g32 + optimizer.weight_decay * p.astype(jnp.float32)
-        m_new = optimizer.momentum * m.astype(jnp.float32) + g32
-        p_new = p.astype(jnp.float32) - lr * m_new
-        return p_new.astype(p.dtype), m_new.astype(m.dtype)
-
-    def _apply(params_t, mom_t, grads_t, lr):
-        """(params', mom') from a matching (params, mom, grads) subtree."""
-        pm = jax.tree_util.tree_map(
-            lambda p_, m_, g_: _upd(p_, m_, g_, lr), params_t, mom_t, grads_t)
-        leaf = lambda x: isinstance(x, tuple)
-        return (jax.tree_util.tree_map(lambda t: t[0], pm, is_leaf=leaf),
-                jax.tree_util.tree_map(lambda t: t[1], pm, is_leaf=leaf))
-
-    def step(params, fb, opt_state, batch, rng):
-        opt_step = opt_state["step"] + 1
-        lr = optimizer.lr(opt_step) if callable(optimizer.lr) else jnp.float32(optimizer.lr)
-
-        fwd = forward_with_error(model, params, cfg, batch)
-        delta_fn = dfa_delta(cfg)
-
-        new_params = dict(params)
-        new_mom = dict(opt_state["mom"])
-        for spec in specs:
-            tape = fwd["saved"][spec.name]
-            fb_seg = fb[spec.name]
-            seg_key = prng.fold_name(rng, spec.name)
-            e_seg = spec.adapt_error(fwd["e_tap"]) if spec.adapt_error else fwd["e_tap"]
-
-            def per_layer(xs, spec=spec, fb_seg=fb_seg, seg_key=seg_key,
-                          extras=tape.extras, e_seg=e_seg):
-                bp, mom_p, xk, idx = xs
-                bmat = fb_lib.feedback_for(fb_seg, idx)
-                kk = jax.random.fold_in(seg_key, idx)
-
-                def local(p):
-                    if cfg.freeze_norms:
-                        p = freeze_norm_leaves(p)
-                    return spec.apply(unshard_fsdp(p), xk, extras)
-
-                (y, _aux), vjp = jax.vjp(local, bp)
-                delta = delta_fn(spec, e_seg, bmat, kk, y)
-                (g,) = vjp((delta.astype(y.dtype), jnp.float32(1.0)))
-                return _apply(bp, mom_p, g, lr)
-
-            xs = (params[spec.name], opt_state["mom"][spec.name], tape.inputs,
-                  jnp.arange(spec.n_layers))
-            new_params[spec.name], new_mom[spec.name] = jax.lax.map(per_layer, xs)
-
-        # head (exact grads) + embed (DFA) updated out-of-loop
-        new_params["head"], new_mom["head"] = _apply(
-            params["head"], opt_state["mom"]["head"], fwd["g_head"], lr)
-        g_embed = embed_grads(model, params, cfg, fwd, fb, rng)
-        if g_embed is not None:
-            new_params["embed"], new_mom["embed"] = _apply(
-                params["embed"], opt_state["mom"]["embed"], g_embed, lr)
-
-        total, _metrics = _totals(fwd)
-        new_opt = {"mom": new_mom, "step": opt_step}
-        return new_params, new_opt, total
-
-    return step
-
-
 def tree_cosine(a, b):
     """cos(a, b) over all leaves of two same-structure pytrees, in f32.
     0.0 for leafless trees (a parameter-free segment has no direction)."""
@@ -370,15 +283,4 @@ class DFAAlgorithm(base.Algorithm):
         return value_and_grad(model, cfg)
 
 
-class FusedDFAAlgorithm(DFAAlgorithm):
-    """Identical gradients to ``dfa``; the fused step consumes each layer's
-    gradient inside the backward map (SGDM-shaped optimizers only)."""
-
-    name = "dfa-fused"
-
-    def fused_step(self, model, cfg: DFAConfig, optimizer):
-        return make_fused_train_step(model, cfg, optimizer)
-
-
 base.register(DFAAlgorithm())
-base.register(FusedDFAAlgorithm())

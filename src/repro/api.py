@@ -3,8 +3,8 @@ backend matrix.
 
 The paper's experiment grid is three independent axes:
 
-* **algo**     — a name in ``repro.algos`` (``bp`` | ``dfa`` | ``dfa-fused``
-  | ``dfa-layerwise`` | anything registered later)
+* **algo**     — a name in ``repro.algos`` (``bp`` | ``dfa`` |
+  ``dfa-layerwise`` | anything registered later)
 * **hardware** — a ``core.photonics`` preset name (``ideal`` |
   ``single_mrr`` | ``offchip_bpd`` | ``onchip_bpd`` | ``digital`` |
   ``emu_ideal`` | ``emu_offchip`` | ``emu_onchip``) or a
@@ -136,11 +136,6 @@ class Session:
     def value_and_grad(self):
         """fn(params, extra_state, batch, rng) -> ((loss, metrics), grads)."""
         return self.algorithm.value_and_grad(self.model, self.config.dfa)
-
-    def fused_step(self, optimizer=None):
-        """Memory-optimised step (algorithm-specific; generic fallback)."""
-        return self.algorithm.fused_step(
-            self.model, self.config.dfa, optimizer or self.config.optimizer)
 
     def evaluate(self, state, batches) -> dict:
         return self.trainer.evaluate(state, batches)

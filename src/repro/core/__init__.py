@@ -1,28 +1,10 @@
-"""Photonic execution model + energy model (+ the ``dfa`` compat alias).
-
-``repro.core.dfa`` is a backwards-compatibility re-export of the engine
-that now lives in ``repro.algos``; it is resolved lazily here so importing
-``repro.core`` (from the algos package itself) never cycles back into the
-algorithm registry.
-"""
+"""Photonic execution model + energy model."""
 
 from repro.core import energy, feedback, photonics
 from repro.core.feedback import FeedbackConfig
 from repro.core.photonics import PhotonicBackend, PhotonicConfig, preset
 
-_DFA_NAMES = ("DFAConfig", "bp_value_and_grad", "init_feedback", "value_and_grad")
-
 __all__ = [
-    "dfa", "energy", "feedback", "photonics",
+    "energy", "feedback", "photonics",
     "FeedbackConfig", "PhotonicBackend", "PhotonicConfig", "preset",
-    *_DFA_NAMES,
 ]
-
-
-def __getattr__(name):
-    if name == "dfa" or name in _DFA_NAMES:
-        import importlib
-
-        _dfa = importlib.import_module("repro.core.dfa")  # lazy: no cycle
-        return _dfa if name == "dfa" else getattr(_dfa, name)
-    raise AttributeError(f"module 'repro.core' has no attribute {name!r}")

@@ -115,16 +115,6 @@ def sigma_to_resolution(sigma: float) -> float:
     return 1.0 - math.log2(sigma) if sigma > 0 else float("inf")
 
 
-def bits_to_std(bits: float) -> float:
-    """Alias of ``resolution_to_sigma`` (historical name)."""
-    return resolution_to_sigma(bits)
-
-
-def std_to_bits(std: float) -> float:
-    """Alias of ``sigma_to_resolution`` (historical name)."""
-    return sigma_to_resolution(std)
-
-
 def fake_quant(x, bits: int | None, amax=None):
     """Symmetric fake quantisation to ``bits`` over [-amax, amax].
 
@@ -209,18 +199,15 @@ def normalise_operands(a, b, cfg: PhotonicConfig):
     return a_n, b_n, s_a, s_b
 
 
-def photonic_matmul(a, b, cfg: PhotonicConfig, key=None, *, mask=None):
+def photonic_matmul(a, b, cfg: PhotonicConfig, key=None):
     """Noisy C = A @ Bᵀ  (the weight-bank product).  Pure-JAX reference path.
 
     a: (..., T, K) — e.g. the error vectors (amplitude-encoded inputs)
     b: (M, K)      — the inscribed weight matrix panel (B(k) rows)
-    mask: optional (..., T, M) Hadamard epilogue (the TIA gain g'(a));
-          applied *after* noise, as on-chip (noise enters at the BPD).
     Returns (..., T, M).
     """
     if not cfg.enabled:
-        out = jnp.einsum("...tk,mk->...tm", a, b)
-        return out * mask if mask is not None else out
+        return jnp.einsum("...tk,mk->...tm", a, b)
 
     a_n, b_n, s_a, s_b = normalise_operands(a, b, cfg)
     out = jnp.einsum("...tk,mk->...tm", a_n, b_n)
@@ -234,8 +221,7 @@ def photonic_matmul(a, b, cfg: PhotonicConfig, key=None, *, mask=None):
             noise = annotate(noise, "delta_tm")
             out = annotate(out, "delta_tm")
         out = out + sigma * noise
-    out = check_finite(out * (s_a * s_b), "photonic_matmul output")
-    return out * mask if mask is not None else out
+    return check_finite(out * (s_a * s_b), "photonic_matmul output")
 
 
 # ---------------------------------------------------------------------------
@@ -249,7 +235,7 @@ def photonic_matmul(a, b, cfg: PhotonicConfig, key=None, *, mask=None):
 
 
 class PhotonicBackend:
-    """Executes C = A @ Bᵀ (+ bank noise, ⊙ mask) with a:(T,K), b:(M,K)."""
+    """Executes C = A @ Bᵀ (+ bank noise) with a:(T,K), b:(M,K)."""
 
     name = "base"
     # True when the backend consumes carried hardware state (drift /
@@ -260,7 +246,7 @@ class PhotonicBackend:
     # one, so under a mesh ``photonic_project`` runs it per shard
     partitionable = True
 
-    def matmul(self, a, b, cfg: PhotonicConfig, key=None, *, mask=None):
+    def matmul(self, a, b, cfg: PhotonicConfig, key=None):
         raise NotImplementedError
 
 
@@ -270,8 +256,8 @@ class ReferenceBackend(PhotonicBackend):
 
     name: str = "ref"
 
-    def matmul(self, a, b, cfg, key=None, *, mask=None):
-        return photonic_matmul(a, b, cfg, key=key, mask=mask)
+    def matmul(self, a, b, cfg, key=None):
+        return photonic_matmul(a, b, cfg, key=key)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -284,11 +270,10 @@ class PallasBackend(PhotonicBackend):
     interpret: bool = False
     partitionable = False
 
-    def matmul(self, a, b, cfg, key=None, *, mask=None):
+    def matmul(self, a, b, cfg, key=None):
         from repro.kernels import ops as kops  # lazy: kernels import us
 
-        return kops.photonic_matmul(a, b, cfg, key=key, mask=mask,
-                                    interpret=self.interpret)
+        return kops.photonic_matmul(a, b, cfg, key=key, interpret=self.interpret)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -310,11 +295,10 @@ class EmulatedMRRBackend(PhotonicBackend):
     partitionable = False
     emu_kernel: str = "auto"
 
-    def matmul(self, a, b, cfg, key=None, *, mask=None):
+    def matmul(self, a, b, cfg, key=None):
         from repro.hardware import channel  # lazy: hardware imports us
 
-        return channel.emulated_matmul(a, b, cfg, key=key, mask=mask,
-                                       kernel=self.emu_kernel)
+        return channel.emulated_matmul(a, b, cfg, key=key, kernel=self.emu_kernel)
 
 
 BACKENDS: dict[str, PhotonicBackend] = {}
@@ -343,9 +327,9 @@ def get_backend(spec: str | PhotonicBackend = "auto") -> PhotonicBackend:
     return BACKENDS[spec]
 
 
-def photonic_project(e, b, cfg: PhotonicConfig, key=None, *, mask=None,
+def photonic_project(e, b, cfg: PhotonicConfig, key=None, *,
                      backend: str | PhotonicBackend = "auto"):
-    """DFA projection  δ = e·Bᵀ (⊙ mask)  through a registered backend.
+    """DFA projection  δ = e·Bᵀ  through a registered backend.
     e: (..., d_tap), b: (d_out, d_tap).
 
     Under an active mesh (``sharding.use_mesh``) a backend whose product
@@ -353,23 +337,22 @@ def photonic_project(e, b, cfg: PhotonicConfig, key=None, *, mask=None,
     partitioner cannot split a kernel call (see ``_per_shard_matmul``)."""
     lead = e.shape[:-1]
     e2 = e.reshape(-1, e.shape[-1])
-    m2 = mask.reshape(-1, mask.shape[-1]) if mask is not None else None
     be = get_backend(backend)
     mesh = current_mesh()
     if mesh is None or be.partitionable:
-        out = be.matmul(e2, b, cfg, key=key, mask=m2)
+        out = be.matmul(e2, b, cfg, key=key)
     else:
-        out = _per_shard_matmul(be, mesh, e2, b, cfg, key, m2)
+        out = _per_shard_matmul(be, mesh, e2, b, cfg, key)
     return out.reshape(*lead, b.shape[0])
 
 
-def _per_shard_matmul(backend: PhotonicBackend, mesh, a, b, cfg, key, mask):
+def _per_shard_matmul(backend: PhotonicBackend, mesh, a, b, cfg, key):
     """``backend.matmul`` inside ``shard_map`` over the mesh's batch axes.
 
-    Each device projects its own rows of ``a`` (and ``mask``) through a
-    replicated ``b``, as a data-parallel replica with a bank of its own
-    would: the amplitude encoding is per shard, and the noise key is folded
-    with the shard's index so that shards draw independent noise.  Carried
+    Each device projects its own rows of ``a`` through a replicated
+    ``b``, as a data-parallel replica with a bank of its own would: the
+    amplitude encoding is per shard, and the noise key is folded with the
+    shard's index so that shards draw independent noise.  Carried
     hardware state (``drift.use_state``) enters replicated.  Rows that do
     not divide over the devices stay replicated and every device computes
     the whole product."""
@@ -380,8 +363,6 @@ def _per_shard_matmul(backend: PhotonicBackend, mesh, a, b, cfg, key, mask):
     split = a.shape[0] % n_shards == 0
     rows = P(axes) if split else P()
     ops, specs = {"a": a, "b": b}, {"a": rows, "b": P()}
-    if mask is not None:
-        ops["mask"], specs["mask"] = mask, rows
     if key is not None:
         ops["key"], specs["key"] = key, P()
     state = drift.active_state() if backend.stateful_hardware else None
@@ -395,8 +376,7 @@ def _per_shard_matmul(backend: PhotonicBackend, mesh, a, b, cfg, key, mask):
         ctx = (drift.use_state(ops["state"]) if "state" in ops
                else contextlib.nullcontext())
         with ctx:
-            return backend.matmul(ops["a"], ops["b"], cfg, key=k,
-                                  mask=ops.get("mask"))
+            return backend.matmul(ops["a"], ops["b"], cfg, key=k)
 
     return jax.shard_map(shard, mesh=mesh, in_specs=(specs,), out_specs=rows,
                          check_vma=False)(ops)

@@ -22,8 +22,7 @@ backends unchanged.  What differs is everything between encode and rescale:
 4.  Per-pass BPD noise: the thermal/read floor (``cfg.noise_std``, same
     convention as the abstract model — per-pass "absolute" or bank
     full-scale) plus signal-dependent shot noise, then the per-pass ADC.
-5.  Passes accumulate digitally; the result is rescaled and the optional
-    Hadamard mask (the TIA gain epilogue) applies after noise, as on chip.
+5.  Passes accumulate digitally and the result is rescaled.
 
 With ``MRRConfig.ideal()`` and ``noise_std=0`` the chain is numerically the
 plain matmul (inscription round-trips exactly); with nonzero ``noise_std``
@@ -341,22 +340,21 @@ def resolve_emu_kernel(spec: str | None = None) -> str:
     return spec
 
 
-def emulated_matmul(a, b, cfg, key=None, *, mask=None, state=None,
+def emulated_matmul(a, b, cfg, key=None, *, state=None,
                     kernel: str | None = None):
     """Device-emulated C = A @ Bᵀ — drop-in for
     ``photonics.photonic_matmul`` (the "emu" backend entry point).
 
-    a: (T, K) amplitude-encoded inputs; b: (M, K) target weights; mask:
-    optional (T, M) post-detection Hadamard epilogue.  ``state`` overrides
-    the drift state; by default the Trainer's active ``drift.use_state``
-    context is consulted, and with neither the bank is drift-free.
+    a: (T, K) amplitude-encoded inputs; b: (M, K) target weights.
+    ``state`` overrides the drift state; by default the Trainer's active
+    ``drift.use_state`` context is consulted, and with neither the bank is
+    drift-free.
     ``kernel`` picks the execution path (``resolve_emu_kernel``): "ref"
     is the unfused chain above; "pallas"/"xla" run the fused panel loop
     of ``kernels.emu_matmul`` (same physics, one kernel per GEMM).
     """
     if not cfg.enabled:
-        out = jnp.einsum("tk,mk->tm", a, b)
-        return out * mask if mask is not None else out
+        return jnp.einsum("tk,mk->tm", a, b)
     kernel = resolve_emu_kernel(kernel)
     a_n, b_n, s_a, s_b = photonics.normalise_operands(a, b, cfg)
     if state is None:
@@ -370,5 +368,4 @@ def emulated_matmul(a, b, cfg, key=None, *, mask=None, state=None,
         out = emu_matmul.fused_bank_product(a_n, b_n, cfg, key,
                                             residual=residual, impl=kernel)
     out = check_finite(out * (s_a * s_b), "emulated_matmul output")
-    out = out * mask if mask is not None else out
     return out.astype(jnp.result_type(a, b))

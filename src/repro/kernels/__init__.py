@@ -1,5 +1,4 @@
 from repro.kernels import emu_matmul, ops, ref
-from repro.kernels.dfa_gradient import dfa_gradient_pallas
 from repro.kernels.emu_matmul import fused_bank_product
 from repro.kernels.photonic_matmul import photonic_matmul_pallas
 
@@ -8,6 +7,5 @@ __all__ = [
     "fused_bank_product",
     "ops",
     "ref",
-    "dfa_gradient_pallas",
     "photonic_matmul_pallas",
 ]

@@ -1,4 +1,4 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public jit'd wrapper around the weight-bank Pallas kernel.
 
 Handles: operand normalisation to the photonic [-1,1] range, fake-quant,
 padding to block multiples, noise-mode selection, and rescaling — so callers
@@ -22,7 +22,6 @@ import jax.numpy as jnp
 
 from repro.core import photonics
 from repro.kernels import ref as kref
-from repro.kernels.dfa_gradient import dfa_gradient_pallas
 from repro.kernels.photonic_matmul import photonic_matmul_pallas
 
 
@@ -40,18 +39,17 @@ def _pad_to(x, mult, axis):
     jax.jit,
     static_argnames=("cfg", "noise_mode", "block_t", "block_m", "block_k", "interpret"),
 )
-def photonic_matmul(a, b, cfg, key=None, *, mask=None, noise_mode="auto",
+def photonic_matmul(a, b, cfg, key=None, *, noise_mode="auto",
                     block_t=128, block_m=128, block_k=512, interpret=False):
     """Weight-bank product with the paper's noise model, kernel-executed.
 
-    a: (T, K) inputs; b: (M, K) weights; mask: optional (T, M) epilogue.
+    a: (T, K) inputs; b: (M, K) weights.
     noise_mode: auto|none|input|prng — "auto" picks `input` when a key is
     given (reproducible, CPU-validatable) and `none` for ideal hardware.
     """
     t, k_dim = a.shape
     if not cfg.enabled:
-        out = a @ b.T
-        return out * mask if mask is not None else out
+        return a @ b.T
 
     a_n, b_n, s_a, s_b = photonics.normalise_operands(a, b, cfg)
 
@@ -86,23 +84,10 @@ def photonic_matmul(a, b, cfg, key=None, *, mask=None, noise_mode="auto",
             # (bits come back zero there — structure-only validation).
             interpret = pltpu.InterpretParams()
 
-    if mask is not None:
-        m_p = _pad_to(_pad_to(mask, bt, 0), bm, 1)
-        out = dfa_gradient_pallas(
-            a_p, b_p, m_p, noise=noise, seed=seed, sigma_step=sigma_step,
-            block_t=bt, block_m=bm, block_k=bk, out_dtype=jnp.float32,
-            interpret=interpret,
-        )
-    else:
-        out = photonic_matmul_pallas(
-            a_p, b_p, noise=noise, seed=seed, sigma_step=sigma_step,
-            block_t=bt, block_m=bm, block_k=bk, out_dtype=jnp.float32,
-            interpret=interpret,
-        )
+    out = photonic_matmul_pallas(
+        a_p, b_p, noise=noise, seed=seed, sigma_step=sigma_step,
+        block_t=bt, block_m=bm, block_k=bk, out_dtype=jnp.float32,
+        interpret=interpret,
+    )
     out = out[:t, : b.shape[0]] * (s_a * s_b)
     return out.astype(a.dtype)
-
-
-def dfa_gradient(a, b, mask, cfg, key=None, **kw):
-    """Fused δ = (A@Bᵀ + η) ⊙ mask — alias with mandatory mask."""
-    return photonic_matmul(a, b, cfg, key, mask=mask, **kw)

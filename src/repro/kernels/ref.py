@@ -14,15 +14,6 @@ def photonic_matmul_ref(a, b, *, noise=None):
     return out.astype(a.dtype)
 
 
-def dfa_gradient_ref(a, b, mask, *, noise=None):
-    """δ = (A @ Bᵀ + η) ⊙ mask."""
-    out = jnp.einsum("tk,mk->tm", a.astype(jnp.float32), b.astype(jnp.float32))
-    if noise is not None:
-        out = out + noise.astype(jnp.float32)
-    out = out * mask.astype(jnp.float32)
-    return out.astype(a.dtype)
-
-
 def total_noise(key, shape, k_dim: int, cfg, dtype=jnp.float32):
     """Draw the accumulated bank noise for a (T,M) output with contraction
     length k_dim — shared by ops.py ("input" mode) and the reference path."""

@@ -9,7 +9,7 @@ Small-scale (this container): runs real steps on the host devices.
       --steps 100 --algo dfa
 
 ``--algo`` accepts any name registered in ``repro.algos`` (bp, dfa,
-dfa-fused, dfa-layerwise, plus anything a plugin registers); ``--preset``
+dfa-layerwise, plus anything a plugin registers); ``--preset``
 is the photonic hardware model (including the device-level ``emu_*``
 presets) and ``--backend`` the execution path (ref | pallas | emu | auto).
 ``--recal-every`` sets the in-situ recalibration cadence for drifting

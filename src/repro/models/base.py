@@ -1,4 +1,4 @@
-"""Model protocol consumed by the DFA engine (core/dfa.py).
+"""Model protocol consumed by the DFA engine (algos/dfa.py).
 
 A DFA-trainable model decomposes into:
 

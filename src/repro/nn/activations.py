@@ -1,10 +1,10 @@
 """Activation functions and their derivatives.
 
-The DFA gradient (paper Eq. 1) needs g'(a) explicitly — on the photonic chip
-it is the per-row TIA gain; here it is the Hadamard mask handed to the fused
-``dfa_gradient`` kernel.  For ReLU the mask is binary, exactly as the paper
-notes ("the elements in the vector g'(a) are binary (0 or 1) when the ReLU
-function is used").
+The DFA gradient (paper Eq. 1) multiplies the projected error by g'(a) — on
+the photonic chip the per-row TIA gain.  Here g'(a) enters through each
+block's local vjp (algos/dfa.py), which differentiates the activation in
+place.  For ReLU it is binary, exactly as the paper notes ("the elements in
+the vector g'(a) are binary (0 or 1) when the ReLU function is used").
 """
 
 from __future__ import annotations

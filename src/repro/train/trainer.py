@@ -1,5 +1,5 @@
 """Trainer: jit'd train step (any algorithm registered in repro.algos:
-bp, dfa, dfa-fused, dfa-layerwise, ...), microbatch accumulation,
+bp, dfa, dfa-layerwise, ...), microbatch accumulation,
 data-parallel batch sharding over the local device mesh, fault-tolerant
 fit loop with checkpoint/auto-resume, straggler deadline hooks, CSV
 metric logging, and optional throughput telemetry (repro.bench).

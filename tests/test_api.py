@@ -9,6 +9,7 @@ import pytest
 from repro import algos, api
 from repro.algos.dfa import grad_alignment
 from repro.core import photonics
+from repro.hardware import mrr
 
 
 # ---------------------------------------------------------------------------
@@ -17,7 +18,7 @@ from repro.core import photonics
 
 def test_registry_exposes_builtin_algorithms():
     names = algos.list_algos()
-    for required in ("bp", "dfa", "dfa-fused", "dfa-layerwise"):
+    for required in ("bp", "dfa", "dfa-layerwise"):
         assert required in names
 
 
@@ -125,30 +126,32 @@ def test_backend_registry_and_unknown_name():
         photonics.get_backend("interferometer")
 
 
-@pytest.mark.parametrize("preset", ["ideal", "digital"])
-def test_ref_vs_pallas_backend_equivalent_noiseless(preset):
-    cfg = photonics.preset(preset)
-    key = jax.random.PRNGKey(3)
-    e = jax.random.normal(key, (5, 7, 10))
-    b = jax.random.normal(jax.random.fold_in(key, 1), (64, 10))
-    out_ref = photonics.photonic_project(e, b, cfg, backend="ref")
-    out_pal = photonics.photonic_project(
-        e, b, cfg, backend=photonics.PallasBackend(interpret=True))
-    assert out_ref.shape == out_pal.shape == (5, 7, 64)
-    np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_pal),
-                               rtol=2e-5, atol=2e-5)
+# e's shape -> B's rows: sub-block, leading batch dims, ragged contraction
+# (33 over 20-column bank panels), several padded row and column blocks
+PROJECT_SHAPES = [((4, 8), 16), ((5, 7, 10), 64), ((3, 7, 33), 61),
+                  ((200, 30), 257)]
 
 
-def test_ref_vs_pallas_backend_equivalent_quantized():
-    """Normalise/fake-quant/rescale is shared — identical through both."""
-    cfg = photonics.PhotonicConfig(noise_std=0.0, weight_bits=6, input_bits=8)
-    key = jax.random.PRNGKey(4)
-    e = jax.random.normal(key, (32, 24))
-    b = jax.random.normal(jax.random.fold_in(key, 1), (48, 24))
+@pytest.mark.parametrize("shape,rows", PROJECT_SHAPES)
+@pytest.mark.parametrize(
+    "hardware", [dict(), dict(weight_bits=6, input_bits=8), dict(enabled=False)],
+    ids=["noiseless", "quantized", "digital"])
+@pytest.mark.parametrize("backend", ["pallas", "emu"])
+def test_photonic_project_backends_agree_with_ref(backend, hardware, shape,
+                                                  rows):
+    """Noiseless, quantised (normalise/fake-quant/rescale is shared, so it
+    is identical through every backend) and photonics off (the plain
+    product); the emu bank on the ideal device."""
+    cfg = photonics.PhotonicConfig(noise_std=0.0, mrr=mrr.MRRConfig.ideal(),
+                                   **hardware)
+    be = photonics.PallasBackend(interpret=True) if backend == "pallas" else backend
+    key = jax.random.PRNGKey(rows)
+    e = jax.random.normal(key, shape)
+    b = jax.random.normal(jax.random.fold_in(key, 1), (rows, shape[-1]))
     out_ref = photonics.photonic_project(e, b, cfg, backend="ref")
-    out_pal = photonics.photonic_project(
-        e, b, cfg, backend=photonics.PallasBackend(interpret=True))
-    np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_pal),
+    out = photonics.photonic_project(e, b, cfg, backend=be)
+    assert out_ref.shape == out.shape == shape[:-1] + (rows,)
+    np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out),
                                rtol=1e-5, atol=1e-5)
 
 

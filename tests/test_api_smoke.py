@@ -45,22 +45,3 @@ def test_dfa_family_reduces_loss_over_a_few_steps(algo):
     for _ in range(30):
         state, metrics = session.step(state, batch)
     assert float(metrics["loss"]) < float(m0["loss"])
-
-
-def test_fused_step_available_for_every_algorithm():
-    """fused_step falls back to compose-with-optimizer when not overridden;
-    dfa-fused provides the real fused path.  All must run one step."""
-    from repro.train.optimizer import SGDM
-
-    for name in algos.list_algos():
-        session = api.build_session(arch="mnist_mlp", smoke=True, algo=name,
-                                    optimizer=SGDM(lr=0.01, momentum=0.9))
-        state = session.init_state()
-        batch = _batch(session.model, jax.random.PRNGKey(2), n=8)
-        step = jax.jit(session.fused_step())
-        new_params, new_opt, loss = step(
-            state["params"], state["fb"], state["opt"], batch,
-            jax.random.PRNGKey(3))
-        assert np.isfinite(float(loss))
-        assert (jax.tree_util.tree_structure(new_params)
-                == jax.tree_util.tree_structure(state["params"]))

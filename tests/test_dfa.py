@@ -6,7 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import dfa, photonics
+from repro.algos import dfa
+from repro.algos.bp import bp_value_and_grad
+from repro.core import photonics
 from repro.core.feedback import FeedbackConfig, make_feedback
 from repro.models.mlp import MLPClassifier
 
@@ -56,7 +58,7 @@ def test_dfa_matches_paper_eq1(setup):
 def test_head_gradient_exactly_matches_backprop(setup):
     model, params, cfg, fb, batch = setup
     (_, _), dfa_g = dfa.value_and_grad(model, cfg)(params, fb, batch, jax.random.PRNGKey(1))
-    (_, _), bp_g = dfa.bp_value_and_grad(model)(params, fb, batch, None)
+    (_, _), bp_g = bp_value_and_grad(model)(params, fb, batch, None)
     align = dfa.grad_alignment(dfa_g, bp_g)
     np.testing.assert_allclose(float(align["head"]), 1.0, atol=1e-5)
 
@@ -64,7 +66,7 @@ def test_head_gradient_exactly_matches_backprop(setup):
 def test_loss_value_identical_dfa_vs_bp(setup):
     model, params, cfg, fb, batch = setup
     (ld, _), _ = dfa.value_and_grad(model, cfg)(params, fb, batch, jax.random.PRNGKey(1))
-    (lb, _), _ = dfa.bp_value_and_grad(model)(params, fb, batch, None)
+    (lb, _), _ = bp_value_and_grad(model)(params, fb, batch, None)
     np.testing.assert_allclose(float(ld), float(lb), rtol=1e-6)
 
 
@@ -84,7 +86,7 @@ def test_alignment_improves_with_training(setup):
     during early training (align-then-memorise, paper ref [29])."""
     model, params, cfg, fb, batch = setup
     vg = jax.jit(dfa.value_and_grad(model, cfg))
-    bp = jax.jit(dfa.bp_value_and_grad(model))
+    bp = jax.jit(bp_value_and_grad(model))
 
     def cos_now(p):
         (_, _), gd = vg(p, fb, batch, jax.random.PRNGKey(0))

@@ -364,7 +364,7 @@ def test_build_session_emu_kernel_requires_emu_backend():
                           hardware="emu_ideal", emu_kernel="bogus")
 
 
-@pytest.mark.parametrize("algo", ["bp", "dfa", "dfa-fused", "dfa-layerwise"])
+@pytest.mark.parametrize("algo", ["bp", "dfa", "dfa-layerwise"])
 def test_session_fused_matches_ref_all_algorithms(algo):
     """One train step per algorithm on a noiseless emu device: the fused
     session must land on the same loss as the unfused one."""

@@ -166,17 +166,6 @@ def test_emu_backend_registered_and_stateful():
         assert photonics.preset(name).mrr is not None
 
 
-def test_emu_matmul_matches_ref_noiseless():
-    key = jax.random.PRNGKey(5)
-    e = jax.random.normal(key, (3, 7, 33))
-    b = jax.random.normal(jax.random.fold_in(key, 1), (61, 33))
-    out_ref = photonics.photonic_project(e, b, photonics.preset("ideal"),
-                                         backend="ref")
-    out_emu = photonics.photonic_project(e, b, _emu_ideal_cfg(), backend="emu")
-    np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_emu),
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_emu_noise_statistics_match_ref():
     """Per-pass BPD noise accumulated over panels == the reference path's
     single draw, statistically (documented noise tolerance: 3%)."""

@@ -43,17 +43,6 @@ def test_photonic_matmul_noiseless_matches_ref(t, k, m, dtype):
         rtol=tol, atol=tol * np.abs(np.asarray(expect)).max() + 1e-6)
 
 
-@pytest.mark.parametrize("t,k,m", SHAPES[:3])
-def test_dfa_gradient_fused_mask(t, k, m):
-    key = jax.random.PRNGKey(0)
-    a = _rand(key, (t, k), jnp.float32)
-    b = _rand(jax.random.fold_in(key, 1), (m, k), jnp.float32)
-    mask = (jax.random.normal(jax.random.fold_in(key, 2), (t, m)) > 0).astype(jnp.float32)
-    out = ops.dfa_gradient(a, b, mask, IDEAL, interpret=True)
-    expect = ref.dfa_gradient_ref(a, b, mask)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=2e-5, atol=1e-4)
-
-
 def test_noise_statistics_match_model():
     """Injected noise std equals σ·s_a·s_b·sqrt(ceil(K/bank_cols))."""
     key = jax.random.PRNGKey(3)

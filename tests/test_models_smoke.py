@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.core import dfa
+from repro.algos import dfa
 
 B, S = 2, 16
 

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import dfa, photonics
+from repro.algos import dfa
+from repro.core import photonics
 from repro.models.mlp import MLPClassifier
 
 DEPTH = 6

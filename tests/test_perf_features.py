@@ -6,9 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs, nn
-from repro.core import dfa
+from repro.algos import dfa
 from repro.models.mamba import MambaConfig, MambaLM
-from repro.train.optimizer import SGDM
 from repro.utils.tree import tree_allclose
 
 
@@ -106,27 +105,6 @@ def test_freeze_norms_zeroes_norm_grads_only():
     assert float(jnp.abs(g["blocks"]["norm2"]["scale"]).max()) == 0.0
     # non-norm params still train
     assert float(jnp.abs(g["blocks"]["attn"]["q"]["w"]).max()) > 0.0
-
-
-def test_fused_train_step_matches_unfused_sgdm():
-    from repro.models.mlp import MLPClassifier
-
-    model = MLPClassifier(in_dim=12, hidden=(24, 16), n_classes=5)
-    key = jax.random.PRNGKey(0)
-    params = model.init(key)
-    cfg = dfa.DFAConfig()
-    fb = dfa.init_feedback(model, key, cfg)
-    opt = SGDM(lr=0.05, momentum=0.9)
-    opt_state = opt.init(params)
-    batch = {"x": jax.random.normal(key, (8, 12)),
-             "y": jax.random.randint(key, (8,), 0, 5)}
-    rng = jax.random.PRNGKey(3)
-    (_, _), grads = dfa.value_and_grad(model, cfg)(params, fb, batch, rng)
-    pa, sa, _ = opt.update(grads, opt_state, params)
-    pb, sb, _ = dfa.make_fused_train_step(model, cfg, opt)(
-        params, fb, opt_state, batch, rng)
-    assert tree_allclose(pa, pb, rtol=1e-5, atol=1e-7)
-    assert tree_allclose(sa["mom"], sb["mom"], rtol=1e-5, atol=1e-7)
 
 
 def test_opt_variants_instantiate_and_train():

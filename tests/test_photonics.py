@@ -12,11 +12,12 @@ from repro.core import photonics
 
 def test_effective_bits_match_paper():
     # Fig. 3(c) and Fig. 5(a): log2(2/σ)
-    assert abs(photonics.std_to_bits(0.019) - 6.72) < 0.01
-    assert abs(photonics.std_to_bits(0.098) - 4.35) < 0.01
-    assert abs(photonics.std_to_bits(0.202) - 3.31) < 0.01
+    assert abs(photonics.sigma_to_resolution(0.019) - 6.72) < 0.01
+    assert abs(photonics.sigma_to_resolution(0.098) - 4.35) < 0.01
+    assert abs(photonics.sigma_to_resolution(0.202) - 3.31) < 0.01
     for bits in [3.31, 4.35, 6.72, 8.0]:
-        assert abs(photonics.std_to_bits(photonics.bits_to_std(bits)) - bits) < 1e-9
+        sigma = photonics.resolution_to_sigma(bits)
+        assert abs(photonics.sigma_to_resolution(sigma) - bits) < 1e-9
 
 
 @hypothesis.given(bits=st.floats(0.25, 40.0))

@@ -4,7 +4,8 @@ pipeline (train with measured hardware noise → evaluate → serve)."""
 import pytest
 
 from repro import configs
-from repro.core import dfa, energy, photonics
+from repro.algos import dfa
+from repro.core import energy, photonics
 from repro.data import mnist, pipeline, tokens
 from repro.models.mlp import MLPClassifier
 from repro.train import SGDM, Trainer, TrainerConfig

@@ -76,18 +76,6 @@ def test_photonic_matmul_compiles(one_chip, noise_mode):
                    _sds((2,), one_chip, jnp.uint32))
 
 
-def test_dfa_gradient_compiles(one_chip):
-    """The masked kernel, noise drawn in-kernel (the "input" mode's noise
-    operand is the same for both kernels and compiles above)."""
-    cfg = photonics.preset("offchip_bpd")
-
-    def fn(a, b, mask, key):
-        return ops.dfa_gradient(a, b, mask, cfg, key, noise_mode="prng")
-
-    _assert_kernel(fn, _sds((T, K), one_chip), _sds((M, K), one_chip),
-                   _sds((T, M), one_chip), _sds((2,), one_chip, jnp.uint32))
-
-
 @pytest.mark.parametrize("preset", ["emu_ideal", "emu_onchip"])
 def test_emu_fused_bank_compiles(one_chip, preset):
     """Noise off (emu_ideal) and on (emu_onchip: BPD read and shot noise
@@ -176,13 +164,10 @@ def _kernel_cases(one_chip):
             interpret=False), (a, b, key)),
         "photonic_matmul": (lambda a, b, key: ops.photonic_matmul(
             a, b, bpd, key, noise_mode="prng"), (a, b, key)),
-        "dfa_gradient": (lambda a, b, mask, key: ops.dfa_gradient(
-            a, b, mask, bpd, key, noise_mode="prng"),
-            (a, b, _sds((256, 256), one_chip), key)),
     }
 
 
-@pytest.mark.parametrize("kernel", ["emu_bank", "photonic_matmul", "dfa_gradient"])
+@pytest.mark.parametrize("kernel", ["emu_bank", "photonic_matmul"])
 def test_kernel_name_in_op_text(one_chip, kernel):
     """Each kernel's call names it in its op text: in ``kernel_metadata``,
     after the operands, which a profile's op event carries (and which a

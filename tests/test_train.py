@@ -6,10 +6,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import dfa, photonics
+from repro import algos
+from repro.algos import dfa
+from repro.core import photonics
 from repro.data import mnist, pipeline
 from repro.models.mlp import MLPClassifier
 from repro.train import SGDM, AdamW, Trainer, TrainerConfig, schedule
+from repro.utils import prng
+from repro.utils.tree import tree_allclose
 
 
 def test_sgdm_matches_manual():
@@ -119,6 +123,38 @@ def test_microbatch_accumulation_matches_full_batch():
     _, m4 = t4.step(s4, batch)
     # CE means over different partitions agree
     assert abs(float(m1["ce_loss"]) - float(m4["ce_loss"])) < 1e-5
+
+
+@pytest.mark.parametrize("algo", algos.list_algos())
+def test_trainer_step_is_value_and_grad_then_optimizer(algo):
+    """One ``Trainer.step`` is the algorithm's ``value_and_grad`` under the
+    step's noise key, then ``optimizer.update``: parameters and momentum
+    match the two composed by hand.  The projections are noisy
+    (offchip_bpd), so the key the step draws from is pinned too."""
+    model = MLPClassifier(in_dim=12, hidden=(24, 16), n_classes=5)
+    opt = SGDM(lr=0.05, momentum=0.9)
+    cfg = TrainerConfig(
+        algo=algo, optimizer=opt, seed=3,
+        dfa=dfa.DFAConfig(photonics=photonics.preset("offchip_bpd"),
+                          backend="ref"))
+    tr = Trainer(model, cfg)
+    state = tr.init_state()
+    kx, ky = jax.random.split(jax.random.PRNGKey(0))
+    batch = {"x": jax.random.normal(kx, (8, 12)),
+             "y": jax.random.randint(ky, (8,), 0, 5)}
+    stepped, _ = tr.step(state, batch)
+
+    vg = algos.get(algo).value_and_grad(model, cfg.dfa)
+    (_, _), grads = vg(state["params"], state["fb"], batch,
+                       prng.step_key(cfg.seed, 0, "noise"))
+    params, opt_state, _ = opt.update(grads, state["opt"], state["params"])
+    assert tree_allclose(stepped["params"], params, rtol=1e-5, atol=1e-7)
+    assert tree_allclose(stepped["opt"]["mom"], opt_state["mom"],
+                         rtol=1e-5, atol=1e-7)
+    if algo != "bp":  # backprop runs no photonic projection
+        (_, _), other = vg(state["params"], state["fb"], batch,
+                           prng.step_key(cfg.seed, 1, "noise"))
+        assert not tree_allclose(other, grads, rtol=1e-5, atol=1e-7)
 
 
 def test_straggler_deadline_raises():
