@@ -1,6 +1,15 @@
 """Attention layers: MHA/GQA (+bias, +qk_norm, +local window), MLA, cross.
 
-Two execution regimes:
+Execution regimes:
+
+* the Pallas flash kernel (``repro.kernels.flash_attention``) — training's
+  self-attention on a TPU, forward and vjp, wherever ``uses_kernel`` says
+  so: S tiles into the kernel's blocks, no window, no logit soft-cap, no
+  active mesh.  It masks causally by index, which is masking by position
+  for the increasing positions every caller passes.
+
+* ``reference_attention`` — the O(S²) oracle, in XLA, for sequences of at
+  most two kv chunks that the kernel does not take (and for the tests).
 
 * ``flash_attention`` — chunked online-softmax attention for training /
   prefill.  Q is processed in static chunks (Python loop ⇒ static bounds);
@@ -26,6 +35,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.photonics import forward_matmul
+from repro.dist.sharding import current_mesh
+from repro.kernels import flash_attention as flash_kernel
 from repro.nn.embeddings import apply_rotary, rotary_angles
 from repro.nn.linear import Linear
 from repro.nn.module import Module, named_key
@@ -174,6 +185,23 @@ def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
     return jnp.concatenate(out_chunks, axis=1).astype(q.dtype)
 
 
+def uses_kernel(s: int, *, window=None, logit_softcap=None) -> bool:
+    """Does self-attention over S tokens run on the Pallas flash kernel:
+    on a TPU, S tiling into its blocks, no window and no soft-cap, and no
+    active mesh (the SPMD partitioner cannot split a kernel call)."""
+    return (window is None and not logit_softcap and current_mesh() is None
+            and flash_kernel.engages(s))
+
+
+def kernel_attention(q, k, v, *, causal=True, scale=None):
+    """The Pallas flash kernel in the layers' layout: q (B, S, H, Dk),
+    k (B, S, KVH, Dk), v (B, S, KVH, Dv) -> (B, S, H, Dv)."""
+    heads_first = lambda x: jnp.swapaxes(x, 1, 2)
+    out = flash_kernel.flash_attention(heads_first(q), heads_first(k), heads_first(v),
+                                       causal=causal, scale=scale)
+    return heads_first(out)
+
+
 def decode_attention(q, k_cache, v_cache, *, cache_len, window=None,
                      q_pos=None, scale=None, logit_softcap=None):
     """Single-step attention vs cache.
@@ -257,7 +285,9 @@ class Attention(Module):
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
         q, k, v = self.qkv(params, x, positions)
-        if s <= 2 * k_chunk:
+        if uses_kernel(s, window=self.window, logit_softcap=self.logit_softcap):
+            out = kernel_attention(q, k, v, causal=self.causal)
+        elif s <= 2 * k_chunk:
             out = reference_attention(q, k, v, q_pos=positions, kv_pos=positions,
                                       causal=self.causal, window=self.window,
                                       logit_softcap=self.logit_softcap)
@@ -475,16 +505,21 @@ class MLAttention(Module):
             [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                       (b, s, h, self.qk_rope_dim))], axis=-1)
         scale = 1.0 / math.sqrt(self.qk_dim)
-        # v_head_dim != qk_dim → pad V for the shared kernels, slice after
-        pad = self.qk_dim - self.v_head_dim
-        v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad))) if pad > 0 else v
-        if s <= 2 * k_chunk:
-            out = reference_attention(q, k, v_p, q_pos=positions,
-                                      kv_pos=positions, causal=True, scale=scale)
+        if uses_kernel(s):
+            out = kernel_attention(q, k, v, causal=True, scale=scale)
         else:
-            out = flash_attention(q, k, v_p, q_pos=positions, kv_pos=positions, causal=True,
-                                  scale=scale, q_chunk=q_chunk, k_chunk=k_chunk)
-        out = out[..., : self.v_head_dim].reshape(b, s, h * self.v_head_dim)
+            # v_head_dim != qk_dim → pad V for the XLA paths, slice after
+            pad = self.qk_dim - self.v_head_dim
+            v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad))) if pad > 0 else v
+            if s <= 2 * k_chunk:
+                out = reference_attention(q, k, v_p, q_pos=positions,
+                                          kv_pos=positions, causal=True, scale=scale)
+            else:
+                out = flash_attention(q, k, v_p, q_pos=positions, kv_pos=positions,
+                                      causal=True, scale=scale, q_chunk=q_chunk,
+                                      k_chunk=k_chunk)
+            out = out[..., : self.v_head_dim]
+        out = out.reshape(b, s, h * self.v_head_dim)
         return forward_matmul(out, params["o"]["w"])
 
     def init_cache(self, batch: int, max_len: int, dtype=None):

@@ -20,7 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core import photonics
 from repro.dist import sharding
-from repro.kernels import emu_matmul, ops
+from repro.kernels import emu_matmul, flash_attention, ops
 
 T, K, M = 4096, 1024, 1024
 
@@ -158,7 +158,9 @@ def _kernel_cases(one_chip):
     bpd, emu = photonics.preset("offchip_bpd"), photonics.preset("emu_onchip")
     key = _sds((2,), one_chip, jnp.uint32)
     a, b = _sds((256, 128), one_chip), _sds((256, 128), one_chip)
+    heads = _sds((1, 2, 256, 64), one_chip)
     return {
+        "flash_attention": (flash_attention.flash_attention, (heads, heads, heads)),
         "emu_bank": (lambda a, b, key: emu_matmul.fused_bank_product(
             a, b, emu, jax.random.wrap_key_data(key), impl="pallas",
             interpret=False), (a, b, key)),
@@ -167,13 +169,14 @@ def _kernel_cases(one_chip):
     }
 
 
-@pytest.mark.parametrize("kernel", ["emu_bank", "photonic_matmul"])
-def test_kernel_name_in_op_text(one_chip, kernel):
+@pytest.mark.parametrize("kernel", ["emu_bank", "photonic_matmul", "flash_attention"])
+def test_kernel_name_in_op_text(one_chip, kernel, monkeypatch):
     """Each kernel's call names it in its op text: in ``kernel_metadata``,
     after the operands, which a profile's op event carries (and which a
     signature match on the operands does not reach), and in the op's
     ``op_name`` (``pallas_call``'s ``name``); no other kernel's name is
     there."""
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
     cases = _kernel_cases(one_chip)
     fn, args = cases[kernel]
     text = _assert_kernel(fn, *args)
@@ -187,13 +190,19 @@ def test_kernel_name_in_op_text(one_chip, kernel):
         assert not [k for k in cases if k != kernel and f'"kernel":"{k}"' in op]
 
 
-def _moe_gmm_reader():
-    """The benchmark's match for the grouped expert matmul's calls."""
-    path = Path(__file__).resolve().parents[1] / "perfbench/kernels/moe_gmm.py"
-    spec = importlib.util.spec_from_file_location("perfbench_moe_gmm", path)
+def _reader(kernel: str):
+    """The benchmark's match (and cost) for one kernel's calls."""
+    path = Path(__file__).resolve().parents[1] / f"perfbench/kernels/{kernel}.py"
+    spec = importlib.util.spec_from_file_location(f"perfbench_{kernel}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def _moe_gmm_reader():
+    """The benchmark's match for the grouped expert matmul's calls."""
+    return _reader("moe_gmm")
+
 
 
 def test_moe_gmm_compiles_at_the_moonlight_cell_shape(one_chip, monkeypatch):
@@ -224,3 +233,41 @@ def test_moe_gmm_compiles_at_the_moonlight_cell_shape(one_chip, monkeypatch):
     assert names == ["moe_gmm"] * 2 + ["moe_gmm_dlhs"] * 2 + ["moe_gmm_drhs"] * 2
     match = _moe_gmm_reader().match
     assert all(match(op) for op in calls)
+
+
+def _op_texts(compiled):
+    """Each kernel call's op text as a profile carries it: the operands'
+    shapes and the kernel's metadata (which spans lines)."""
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = "\n".join(mod.to_string(opts) for mod in
+                     compiled.runtime_executable().hlo_modules())
+    ops = re.split(r"\n(?= *(?:ROOT )?[\w.-]+ = )", text)
+    return [op.strip() for op in ops if 'custom_call_target="tpu_custom_call"' in op]
+
+
+@pytest.mark.parametrize("b,h,s,dk,dv", [
+    (8, 16, 1024, 64, 64),     # qwen1.5-0.5b: 8 × 1024 tokens, 16 heads of 64
+    (2, 16, 8192, 192, 128),   # Moonlight's MLA: 2 × 8192, q/k 192, v 128
+])
+def test_flash_attention_compiles_at_the_cell_shapes(one_chip, b, h, s, dk, dv, monkeypatch):
+    """The flash kernel's forward and its vjp at each training cell's
+    shape compile for the chip; each call is found by the benchmark's
+    ``flash_attention`` match and by no other kernel's, ``emu_bank``'s
+    signature match included, so ``kernel_ms.emu_bank`` reads what it did."""
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+
+    def fn(q, k, v, ct):
+        o, vjp = jax.vjp(lambda q, k, v: flash_attention.flash_attention(q, k, v), q, k, v)
+        return o, vjp(ct)
+
+    qk, vs = _sds((b, h, s, dk), one_chip), _sds((b, h, s, dv), one_chip)
+    compiled = jax.jit(fn).lower(qk, qk, vs, vs).compile()
+    calls = _op_texts(compiled)
+    names = sorted(re.search(r'"kernel":"(\w+)"', op).group(1) for op in calls)
+    assert names == ["flash_attention", "flash_attention_bwd"]
+    others = sorted(p.stem for p in (Path(__file__).resolve().parents[1]
+                                      / "perfbench/kernels").glob("*.py"))
+    assert {"emu_bank", "moe_gmm", "flash_attention"} <= set(others)
+    for op in calls:
+        assert [k for k in others if _reader(k).match(op)] == ["flash_attention"]
